@@ -373,7 +373,7 @@ if __name__ == "__main__":
                     help="JSON config for bench_sharded_inprocess")
     _a = ap.parse_args()
     # always through enable_compilation_cache: it respects an inherited
-    # cache dir AND pins the non-thunk CPU runtime the cache requires
+    # cache dir
     from benchmarks.run import enable_compilation_cache
 
     enable_compilation_cache()

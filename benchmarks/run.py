@@ -15,9 +15,10 @@ systems layer. Prints ``name,key=value,...`` CSV lines.
 
 The majority-voting sections run on the engine backend selected with
 ``--backend {numpy,jax}`` (default numpy — the reference simulator).
-The JAX persistent compilation cache is enabled (results/.jax_cache) so
-the device engine's superstep programs compile once across benchmark
-invocations instead of ~4s of jit per size per run.
+The JAX persistent compilation cache is enabled (``JAX_COMPILATION_CACHE_DIR``
+if set, else results/.jax_cache under the repo root) so the device
+engine's superstep programs compile once across benchmark invocations
+instead of ~4s of jit per size per run.
 
 Run everything:   PYTHONPATH=src python -m benchmarks.run
 One section:      PYTHONPATH=src python -m benchmarks.run --only stationary
@@ -36,7 +37,11 @@ import os
 import sys
 import time
 
-CACHE_DIR = os.path.join("results", ".jax_cache")
+# the persistent XLA compilation cache when JAX_COMPILATION_CACHE_DIR is
+# unset: a fixed path in the checkout (git-ignored), anchored on the
+# repo root so every cwd shares it — the path is part of the cache key
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CACHE_DIR = os.path.join(REPO_ROOT, "results", ".jax_cache")
 CACHE_KEY_FILE = "CACHE_KEY"
 
 
@@ -50,16 +55,14 @@ def section(name):
 
 def cache_key() -> str:
     """What a persistent-cache entry's validity depends on: the jaxlib
-    that serialized it, the engine program schema it was traced from
-    (`repro.engine.ENGINE_SCHEMA`), and the CPU runtime flag regime
-    below. Any mismatch means the cached executables were built against
-    a different world."""
+    that serialized it and the engine program schema it was traced from
+    (`repro.engine.ENGINE_SCHEMA`). Any mismatch means the cached
+    executables were built against a different world."""
     import jaxlib
 
     from repro.engine import ENGINE_SCHEMA
 
-    return (f"jaxlib={jaxlib.__version__};engine_schema={ENGINE_SCHEMA};"
-            f"cpu_thunk=off")
+    return f"jaxlib={jaxlib.__version__};engine_schema={ENGINE_SCHEMA}"
 
 
 def validate_cache_dir(cache_dir: str, key: str = None, log=None) -> str:
@@ -67,12 +70,18 @@ def validate_cache_dir(cache_dir: str, key: str = None, log=None) -> str:
     cache entries serialized against an older jaxlib/engine deserialized
     into executables that hung armed-engine runs ~1-in-3).
 
-    The dir carries a `CACHE_KEY` marker written on first use. Returns
-    the action taken: ``"fresh"`` (new/empty dir — marker written),
-    ``"match"`` (marker equals today's key — entries reusable), or
-    ``"cleared"`` (marker missing or different on a non-empty dir — the
-    whole dir is torn down and re-marked; recompiling costs seconds,
-    debugging a poisoned executable cost a day)."""
+    The dir carries a `CACHE_KEY` marker written when this function
+    creates it. Returns the action taken:
+
+      * ``"fresh"``   — new or empty dir: marker written;
+      * ``"match"``   — marker equals today's key: entries reusable;
+      * ``"cleared"`` — marker differs: the dir is one this function
+        made for another key, so it is torn down and re-marked
+        (recompiling costs seconds, debugging a poisoned executable
+        cost a day);
+      * ``"foreign"`` — entries but no marker: a directory this
+        function did not create. Nothing in it is deleted or marked;
+        the caller must not use it."""
     import shutil
 
     key = key if key is not None else cache_key()
@@ -87,13 +96,14 @@ def validate_cache_dir(cache_dir: str, key: str = None, log=None) -> str:
             return "match"
         action = "cleared"
     elif entries:
-        action = "cleared"  # unmarked non-empty dir: provenance unknown
+        if log:
+            log(f"jax_cache_foreign,dir={cache_dir},reason=unmarked_entries")
+        return "foreign"
     else:
         action = "fresh"
     if action == "cleared":
         if log:
-            log(f"jax_cache_cleared,dir={cache_dir},"
-                f"reason=key_mismatch_or_unmarked")
+            log(f"jax_cache_cleared,dir={cache_dir},reason=key_mismatch")
         shutil.rmtree(cache_dir, ignore_errors=True)
     os.makedirs(cache_dir, exist_ok=True)
     with open(marker, "w") as f:
@@ -102,32 +112,26 @@ def validate_cache_dir(cache_dir: str, key: str = None, log=None) -> str:
 
 
 def enable_compilation_cache(cache_dir: str = CACHE_DIR):
-    """Persistent XLA compilation cache: the engine's superstep programs
-    are ~4s of jit per (backend, size) — cache them across benchmark
-    invocations. Must run before the first jit call (before the CPU
-    client initializes, for the XLA_FLAGS injection below to apply).
+    """Turn on JAX's persistent compilation cache and return its
+    directory (None if it stays off). Must run before the first jit.
 
-    The XLA:CPU *thunk* runtime (this jaxlib's default) is excluded:
-    its serialized executables can deserialize into code that spins
-    forever (observed ~1-in-3 cache-hit runs hung with the busy thread
-    executing inside JIT'd code pages; 0 hangs with the flag). The
-    non-thunk runtime also runs the superstep ~2x faster on CPU, so
-    every run.py measurement — committed baselines and the
-    check-regression re-measurements alike — shares this basis. The
-    flag goes through the environment so sharded-row subprocesses
-    (which append their virtual-device flag to inherited XLA_FLAGS)
-    stay on the same runtime as the parent."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_cpu_use_thunk_runtime" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_cpu_use_thunk_runtime=false").strip()
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it:
+    the directory is placed from outside, so this sets none in code and
+    touches nothing in it. Otherwise the cache lives at `cache_dir`
+    (default: the repo-root-anchored `CACHE_DIR`), guarded by
+    `validate_cache_dir`; a foreign directory there leaves the cache
+    off."""
     import jax
 
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", cache_dir)
-    validate_cache_dir(cache_dir, log=csv)
-    jax.config.update("jax_compilation_cache_dir", os.path.abspath(cache_dir))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return os.environ["JAX_COMPILATION_CACHE_DIR"]
+    cache_dir = os.path.abspath(cache_dir)
+    if validate_cache_dir(cache_dir, log=csv) == "foreign":
+        return None
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
 
 
 def main() -> None:

@@ -15,10 +15,11 @@ warnings.simplefilter("ignore")
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.tree_collectives import (
-    _parent, shard_map, tree_all_reduce, tree_broadcast, tree_reduce,
+    _parent, tree_all_reduce, tree_broadcast, tree_reduce,
 )
 
 

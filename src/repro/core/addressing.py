@@ -201,12 +201,8 @@ def in_subtree(x: Array, y: Array, d: int) -> Array:
     lo = x - s  # exclusive lower bound
     size = _masked((s << one) - one, d)  # 2s - 1 addresses in the subtree
     rel = _masked(y - lo - one, d)
-    inside = rel < size
-    if _is_jax(x):
-        import jax.numpy as jnp
-
-        return jnp.where(x == 0, jnp.ones_like(inside), inside)
-    return np.where(np.asarray(x) == 0, True, inside)
+    # mask logic, not a select: Pallas TPU kernels cannot select bools
+    return (x == 0) | (rel < size)
 
 
 def is_foreparent(x: Array, y: Array, d: int) -> Array:
@@ -222,12 +218,8 @@ def in_cw_subtree(x: Array, y: Array, d: int) -> Array:
     one = _const(x, 1)
     rel = _masked(y - x - one, d)
     inside = rel < (s - one)
-    root_case = y != 0  # CW subtree of the root is every non-zero address
-    if _is_jax(x):
-        import jax.numpy as jnp
-
-        return jnp.where(x == 0, root_case, inside)
-    return np.where(np.asarray(x) == 0, root_case, inside)
+    root = x == 0  # CW subtree of the root is every non-zero address
+    return (root & (y != 0)) | (~root & inside)
 
 
 @_wrapok

@@ -32,22 +32,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-import warnings
-
-with warnings.catch_warnings():
-    warnings.simplefilter("ignore", DeprecationWarning)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f=None, /, **kw):
-    """Version-compat shard_map (check_rep in 0.8.x, check_vma later)."""
-    if "check_vma" in kw:
-        kw["check_rep"] = kw.pop("check_vma")
-    if f is None:
-        return lambda g: _shard_map(g, **kw)
-    return _shard_map(f, **kw)
 
 
 def _levels(p: int) -> int:

@@ -30,8 +30,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro.core.tree_collectives import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 F32 = jnp.float32

@@ -42,8 +42,8 @@ EngineResult = Dict[str, float]
 
 # Compiled-program schema version. Bump whenever the DeviceState layout,
 # the wheel row format, or the semantics of any jitted engine program
-# change: the persistent XLA compilation cache is keyed on
-# (jaxlib version, ENGINE_SCHEMA) by `benchmarks.run.validate_cache_dir`,
+# change: the repo's own persistent XLA compilation cache dir is keyed
+# on (jaxlib version, ENGINE_SCHEMA) by `benchmarks.run.validate_cache_dir`,
 # so a cache dir serialized against an older engine is detected and
 # cleared instead of deserializing into poisoned executables (the PR 8
 # "stale .jax_cache hangs armed-engine runs" scar).

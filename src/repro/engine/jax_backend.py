@@ -106,9 +106,12 @@ from repro.engine import protocol as P
 from repro.engine.base import (EngineResult, coalesced_update,
                                run_convergence_loop)
 from repro.engine.problems import Majority, get_problem
-from repro.kernels.majority_step.ops import _on_tpu, majority_step
-from repro.kernels.wheel import (WHEEL_KERNELS, descent_tail, due_dedup,
-                                 stage_rows, threshold_step)
+from repro.kernels import on_tpu
+from repro.kernels.majority_step.ops import majority_step
+from repro.kernels.wheel import (WHEEL_KERNELS, descent_reference,
+                                 descent_tail, due_dedup, stage_rows,
+                                 threshold_step)
+from repro.kernels.wheel._common import in_segment
 
 NDIR = 3
 _I32 = jnp.int32
@@ -157,6 +160,29 @@ def _next_pow2(v: int) -> int:
     return p
 
 
+# Device payload layout. A peer's three per-direction payloads are kept
+# as one (..., 3P) row of "payload columns", component-major: column
+# c*3 + v holds component c of direction v — the layout of the X_out
+# columns of `DeviceState.out`, and, transposed, the lane-dense planes
+# of the `threshold_step` kernel. No device program builds a (..., 3, P)
+# array: on the TPU such a tiny-minor-dims relayout costs 40-230 s of
+# compile (tests/test_tpu_compile.py keeps the kernels honest; the
+# engine programs were rehearsed the same way).
+
+
+def peer_links(inbox, pw: int):
+    """(..., rows*3, P+1) per-link inbox -> (..., rows, 3(P+1)) one row
+    per peer, column v*(P+1) + c (a row-major view, no data movement)."""
+    return inbox.reshape(*inbox.shape[:-2], -1, NDIR * (pw + 1))
+
+
+def link_cols(rows, pw: int):
+    """(..., 3(P+1)) peer link rows -> (..., 3P) received payload
+    columns (component-major, column c*3 + v)."""
+    return jnp.stack([rows[..., v * (pw + 1) + c]
+                      for c in range(pw) for v in range(NDIR)], axis=-1)
+
+
 def knowledge(problem, inbox, x, pd: int):
     """(..., pd, P) knowledge payloads K = X_self + sum_v X_in from the
     flat per-link inbox. The ONE inbox-based definition — the
@@ -164,8 +190,9 @@ def knowledge(problem, inbox, x, pd: int):
     (batched included) and the churn mover payloads all read it; keep
     them in lockstep. `x` is the (..., pd, D) own-data plane."""
     pw = problem.payload_width
-    lead = inbox.shape[:-2]
-    k = inbox[..., :pw].reshape(*lead, pd, NDIR, pw).sum(-2)
+    r = peer_links(inbox, pw)
+    k = jnp.stack([r[..., c] + r[..., pw + 1 + c] + r[..., 2 * (pw + 1) + c]
+                   for c in range(pw)], axis=-1)
     one = jnp.ones_like(x[..., :1])
     return k + jnp.concatenate([x, one], axis=-1)
 
@@ -215,46 +242,14 @@ def deliver_network_step(*, origin, dest, edge, has_edge, live, pos_i,
     This is THE delivery semantics of the device engine; the parity
     tests drive this exact function against `routing.step_batch`, for
     ordinary traffic and for Alg. 2 ALERTs alike (an ALERT differs only
-    in riding the side-wheel, never in routing).
+    in riding the side-wheel, never in routing). The loop itself is
+    `kernels.wheel.descent_reference`, which the blocked `descent_tail`
+    kernel runs per block.
     """
-    def cond(c):
-        return c[0].any()
-
-    def body(c):
-        (lv, entry, cur_dest, cur_edge, cur_he,
-         acc, drop, o_dest, o_edge, o_he) = c
-        dlv = P.deliver_rules(
-            jnp, origin=origin, dest=cur_dest, edge=cur_edge,
-            has_edge=cur_he, network_entry=entry, pos_i=pos_i,
-            a_prev=a_prev, a_self=a_self, self_seg=self_seg,
-            max_addr=max_addr, d=d, repair=True,
-        )
-        now_acc = lv & dlv.accept
-        now_drop = lv & dlv.drop & ~dlv.accept
-        moving = lv & ~dlv.accept & ~dlv.drop
-        # R1: keep descending while the new destination is still ours
-        stay = moving & JaxEngine._in_segment(dlv.new_dest, a_prev, a_self)
-        fwd = moving & ~stay
-        return (
-            stay, entry & ~stay,
-            jnp.where(stay, dlv.new_dest, cur_dest),
-            jnp.where(stay, dlv.new_edge, cur_edge),
-            jnp.where(stay, dlv.new_has_edge, cur_he),
-            acc | now_acc, drop | now_drop,
-            jnp.where(fwd, dlv.new_dest, o_dest),
-            jnp.where(fwd, dlv.new_edge, o_edge),
-            jnp.where(fwd, dlv.new_has_edge, o_he),
-        )
-
-    false_b = jnp.zeros(live.shape, bool)
     if entry is None:
         entry = jnp.ones(live.shape, bool)
-    init = (live, entry, dest, edge, has_edge,
-            false_b, false_b, dest, edge, has_edge)
-    (_, _, _, _, _, acc, drop, o_dest, o_edge, o_he) = jax.lax.while_loop(
-        cond, body, init
-    )
-    return acc, drop, o_dest, o_edge, o_he
+    return descent_reference(origin, dest, edge, has_edge, live, entry,
+                             pos_i, a_prev, a_self, self_seg, max_addr, d)
 
 
 class DeviceState(NamedTuple):
@@ -466,7 +461,7 @@ class JaxEngine:
         # parity tests, not throughput). The fused kernel implements the
         # majority rule only — other problems run the jnp rules.
         self._is_majority = isinstance(self.problem, Majority)
-        kernel_on = kernel == "pallas" or (kernel == "auto" and _on_tpu())
+        kernel_on = kernel == "pallas" or (kernel == "auto" and on_tpu())
         self._use_kernel = kernel_on and self._is_majority
         # delivery-wheel kernels (kernels.wheel): each has an individual
         # XLA fallback; `wheel_kernels` selects the enabled subset by
@@ -486,7 +481,7 @@ class JaxEngine:
                 f"unknown wheel kernels {sorted(bad)}; "
                 f"pick from {WHEEL_KERNELS}")
         self._wk = frozenset(wk_names) if kernel_on else frozenset()
-        self._wk_interp = not _on_tpu()
+        self._wk_interp = not on_tpu()
         # fault plane (DESIGN.md §10). Arming adds the probe side-channel
         # to the cycle program; disarmed engines trace the exact pre-fault
         # program (every fault branch is a Python-level `if` on the
@@ -636,9 +631,12 @@ class JaxEngine:
                   q: jnp.ndarray) -> jnp.ndarray:
         """Peer row owning each address (successor with wrap) — one
         binary search over the padded sorted-prefix table (the NO_ADDR
-        sentinels sort above every query)."""
-        return (jnp.searchsorted(addrs, q, side="left").astype(_I32)
-                % n_live.astype(_I32))
+        sentinels sort above every query, so the search lands in
+        [0, n_live] and one conditional subtract wraps it: a vector
+        `%` by a traced divisor costs the TPU compiler ~40 s)."""
+        i = jnp.searchsorted(addrs, q, side="left").astype(_I32)
+        n = n_live.astype(_I32)
+        return jnp.where(i >= n, i - n, i)
 
     def _lane_of(self, addrs: jnp.ndarray, n_live: jnp.ndarray,
                  dest: jnp.ndarray) -> jnp.ndarray:
@@ -650,21 +648,17 @@ class JaxEngine:
     def _ring_views(self, addrs: jnp.ndarray, n_live: jnp.ndarray) -> dict:
         """Recompute prev/pos from the padded address table (vacant rows
         hold garbage; they are never dereferenced — owner lookups return
-        occupied rows only)."""
-        idx = jnp.arange(addrs.shape[0], dtype=_I32)
-        prev = addrs[(idx - 1) % n_live.astype(_I32)]
+        occupied rows only). Row 0 wraps to the last occupied row by a
+        select, not a vector `%` (~40 s of TPU compile)."""
+        j = jnp.arange(addrs.shape[0], dtype=_I32) - 1
+        prev = addrs[jnp.where(j < 0, n_live.astype(_I32) - 1, j)]
         pos = A.position_from_segment(prev, addrs, self.d)
         return {"prev": prev, "pos": pos}
 
-    @staticmethod
-    def _in_segment(addr, a_prev, a_self):
-        """Does `addr` fall in the segment (a_prev, a_self]? O(1) ownership
-        test given the segment edges; the wrapped (root) segment has
-        a_prev >= a_self."""
-        wrapped = a_prev >= a_self
-        inside = (addr > a_prev) & (addr <= a_self)
-        inside_wrap = (addr > a_prev) | (addr <= a_self)
-        return jnp.where(wrapped, inside_wrap, inside)
+    # does `addr` fall in the segment (a_prev, a_self]? O(1) ownership
+    # test given the segment edges; the wrapped (root) segment has
+    # a_prev >= a_self
+    _in_segment = staticmethod(in_segment)
 
     @staticmethod
     def _compact(mask: jnp.ndarray, budget: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -682,23 +676,41 @@ class JaxEngine:
         return idx, cum
 
     @staticmethod
-    def _group_ranks(g: jnp.ndarray, live: jnp.ndarray, n_groups: int):
+    def _group_ranks(g: jnp.ndarray, live: jnp.ndarray, n_groups: int,
+                     scan: bool = None):
         """Stable within-group ranks + per-group counts for a flat row
-        batch: rank[i] = #live rows j < i with g[j] == g[i]. One stable
-        argsort over the group keys (dead rows key to `n_groups`), a
-        searchsorted for the group starts, and a scatter back — the
-        deterministic multi-append primitive of the partitioned wheel
-        (ranks depend only on the GLOBAL row order, which the boundary
-        exchange fixes lane-major, so appends are mesh-invariant)."""
+        batch: rank[i] = #live rows j < i with g[j] == g[i] (dead rows
+        rank 0; callers never read them). The deterministic multi-append
+        primitive of the partitioned wheel (ranks depend only on the
+        GLOBAL row order, which the boundary exchange fixes lane-major,
+        so appends are mesh-invariant).
+
+        Two exact forms of one result, chosen by the platform compiled
+        for (`scan=None`): off the TPU one stable argsort — O(m log m),
+        the cheapest on a CPU; on the TPU one masked cumsum per group
+        key — O(n_groups * m) elementwise work and no sort, since the
+        TPU compiler spends 20-40 s on every sort and every engine
+        program appends."""
         m = g.shape[0]
         key = jnp.where(live, g, n_groups).astype(_I32)
-        order = jnp.argsort(key, stable=True).astype(_I32)
-        ks = key[order]
-        first = jnp.searchsorted(ks, ks, side="left").astype(_I32)
-        rank_sorted = jnp.arange(m, dtype=_I32) - first
-        rank = jnp.zeros(m, _I32).at[order].set(rank_sorted)
-        counts = jnp.zeros(n_groups + 1, _I32).at[key].add(1)[:n_groups]
-        return rank, counts
+        if not (on_tpu() if scan is None else scan):
+            order = jnp.argsort(key, stable=True).astype(_I32)
+            ks = key[order]
+            first = jnp.searchsorted(ks, ks, side="left").astype(_I32)
+            rank = jnp.zeros(m, _I32).at[order].set(
+                jnp.arange(m, dtype=_I32) - first)
+            counts = jnp.zeros(n_groups + 1, _I32).at[key].add(1)
+            return jnp.where(live, rank, 0), counts[:n_groups]
+
+        def one(k, c):
+            rank, counts = c
+            hit = key == k
+            cs = jnp.cumsum(hit.astype(_I32))
+            return jnp.where(hit, cs - 1, rank), counts.at[k].set(cs[-1])
+
+        return jax.lax.fori_loop(
+            0, n_groups, one,
+            (jnp.zeros(m, _I32), jnp.zeros(n_groups, _I32)))
 
     def _append_rows(self, buf, cnt, rows, lane, slot, live, cap, base):
         """Append the GLOBAL `rows` batch into the local lane arenas.
@@ -731,48 +743,38 @@ class JaxEngine:
         attempted = counts_loc.sum(1)
         return nbuf, ncnt, attempted, attempted - added.sum(1)
 
-    def _out_pay(self, out: jnp.ndarray) -> jnp.ndarray:
-        """(..., 3P+1) out rows -> (..., 3, P) X_out payload planes
-        (component-major columns, the majority-era [ones*3, total*3]
-        layout generalized)."""
-        pw = self.pw
-        comps = [out[..., c * NDIR:(c + 1) * NDIR] for c in range(pw)]
-        return jnp.stack(comps, axis=-1)
-
     def _pack_out(self, pay: jnp.ndarray, seq: jnp.ndarray) -> jnp.ndarray:
-        """Inverse of `_out_pay`: (..., 3, P) payload + (...,) seq ->
-        (..., 3P+1) out rows."""
-        comps = [pay[..., c] for c in range(self.pw)]
-        return jnp.concatenate(comps + [seq[..., None]], axis=-1)
+        """(..., 3P) payload columns + (...,) seq -> (..., 3P+1) out rows
+        (the X_out columns ARE the payload column layout)."""
+        return jnp.concatenate([pay, seq[..., None]], axis=-1)
 
-    def _rules(self, in_pay, out_pay, x):
-        """Problem-generic threshold rules dispatch: the fused Pallas
-        `threshold_step` kernel when enabled (any problem — the kernel
-        traces the problem's own `test`), else the shared jnp rules.
-        Returns (viol, out, pay) — bit-identical either way."""
+    def _rules(self, in_cols, out_cols, x):
+        """Problem-generic threshold rules dispatch over (N, 3P) payload
+        columns: the fused Pallas `threshold_step` kernel when enabled
+        (any problem — the kernel traces the problem's own `test_cols`),
+        else the shared jnp rules. Returns (viol (N,3), out (N,),
+        pay (N,3P)) — bit-identical either way."""
         if "threshold" in self._wk:
-            return threshold_step(self.problem, in_pay, out_pay, x,
+            return threshold_step(self.problem, in_cols, out_cols, x,
                                   use_kernel=True, interpret=self._wk_interp)
-        return P.threshold_rules(self.problem, jnp, in_pay, out_pay, x)
+        return P.threshold_rules_cols(self.problem, jnp, in_cols, out_cols, x)
 
     def _test_phase(self, st: DeviceState):
         """Full-width threshold rules (event paths + parity surface):
         the fused Pallas kernel for the majority problem on TPU, the
         problem-generic `threshold_step` kernel when wheel kernels are
         on, the shared jnp rules elsewhere. Returns (viol (pd,3),
-        pay (pd,3,P))."""
-        pd = st.x.shape[0]
+        pay (pd,3P) payload columns)."""
         pw = self.pw
+        in_cols = link_cols(peer_links(st.inbox, pw), pw)
+        out_cols = st.out[:, :NDIR * pw]
         if self._is_majority and "threshold" not in self._wk:
-            io = st.inbox[:, 0].reshape(pd, NDIR)
-            it = st.inbox[:, 1].reshape(pd, NDIR)
             viol, _, po, pt = majority_step(
-                io, it, st.out[:, 0:3], st.out[:, 3:6], st.x[:, 0],
-                use_kernel=self._use_kernel,
+                in_cols[:, 0:3], in_cols[:, 3:6], out_cols[:, 0:3],
+                out_cols[:, 3:6], st.x[:, 0], use_kernel=self._use_kernel,
             )
-            return viol, jnp.stack([po, pt], axis=-1)
-        in_pay = st.inbox[:, :pw].reshape(pd, NDIR, pw)
-        viol, _, pay = self._rules(in_pay, self._out_pay(st.out), st.x)
+            return viol, jnp.concatenate([po, pt], axis=-1)
+        viol, _, pay = self._rules(in_cols, out_cols, st.x)
         return viol, pay
 
     def _outputs_match(self, st: DeviceState, truth: jnp.ndarray) -> jnp.ndarray:
@@ -801,8 +803,8 @@ class JaxEngine:
         GLOBAL event block (callers `gather_events` first), so the
         within-group append ranks are mesh-invariant; each plane appends
         only the rows whose owner lane it holds. ALERT rows go to the
-        side-wheel, due immediately. All args are flat: (m,) meta
-        columns and (m, P) payload."""
+        side-wheel, due immediately. All args are flat (m,) columns;
+        `pay` is the sequence of the P payload components."""
         m = cand.shape[0]
         u = lambda a: a.astype(_U32)
         if alert:
@@ -813,7 +815,7 @@ class JaxEngine:
             )
         rows = jnp.stack(
             [u(origin), u(dest), u(edge), u(has_edge)]
-            + [u(pay[:, c]) for c in range(self.pw)]
+            + [u(p) for p in pay]
             + [u(seq), u(due)],
             axis=1,
         )  # (m, roww)
@@ -844,10 +846,11 @@ class JaxEngine:
             rows_l = (self._plane.lane_base(st.wcnt.shape[0])
                       * self.lane_rows + jnp.arange(pd, dtype=_I32))
             touched = touched & ~st.dead[rows_l]  # the dead never send
-        viol, pay = self._test_phase(st)  # (pd,3), (pd,3,P)
+        viol, pay = self._test_phase(st)  # (pd,3), (pd,3P)
         eff = viol & touched[:, None]
-        seq = st.out[:, NDIR * self.pw] + eff.any(1).astype(_I32)
-        new_pay = jnp.where(eff[..., None], pay, self._out_pay(st.out))
+        pw = self.pw
+        seq = st.out[:, NDIR * pw] + eff.any(1).astype(_I32)
+        new_pay = jnp.where(jnp.tile(eff, (1, pw)), pay, st.out[:, :NDIR * pw])
         st = st._replace(out=self._pack_out(new_pay, seq))
         pos_l, addrs_l, prev_l = self._plane.local_tables(st)
         dirs = jnp.broadcast_to(jnp.arange(NDIR, dtype=_I32)[None, :], (pd, NDIR))
@@ -856,11 +859,11 @@ class JaxEngine:
             jnp, bc(pos_l), dirs, bc(addrs_l), bc(prev_l), d
         )
         cand = (eff & valid).reshape(-1)
-        (cand, origin, dest, edge, has_edge, pay_g, seq_g) = \
+        (cand, origin, dest, edge, has_edge, seq_g, *pay_g) = \
             self._plane.gather_events(
                 cand, origin.reshape(-1), dest.reshape(-1),
-                edge.reshape(-1), has_edge.reshape(-1),
-                pay.reshape(-1, self.pw), bc(seq).reshape(-1))
+                edge.reshape(-1), has_edge.reshape(-1), bc(seq).reshape(-1),
+                *[a.reshape(-1) for a in P.comps(pay, pw)])
         return self._enqueue_events(
             st, cand, origin, dest, edge, has_edge, pay_g, seq_g,
             alert=False,
@@ -1104,11 +1107,11 @@ class JaxEngine:
         rvalid = reps_w < WW
         reps_safe = jnp.where(rvalid, reps_w, 0)
         rp = jnp.where(rvalid, recv[reps_safe], 0)
-        link = rp[:, None] * NDIR + jnp.arange(NDIR, dtype=_I32)[None, :]
-        rin = pl.take_link(inbox, link)        # (WW, 3, P+1)
-        ro = pl.take_peer(st.out, rp)          # (WW, 3P+1)
+        pw = self.pw
+        rin = pl.take_peer(peer_links(inbox, pw), rp)   # (WW, 3(P+1))
+        ro = pl.take_peer(st.out, rp)                    # (WW, 3P+1)
         viol, _, pay = self._rules(
-            rin[..., :self.pw], self._out_pay(ro), pl.take_peer(st.x, rp)
+            link_cols(rin, pw), ro[:, :NDIR * pw], pl.take_peer(st.x, rp)
         )
         if aforce is None:
             force = (pl.link_read3(abest, rp) >= 0) & has_alerts
@@ -1120,9 +1123,9 @@ class JaxEngine:
             # repairs whatever state the drop faults destroyed)
             force = force | (pl.link_read3(pbest, rp) >= 0)
         eff = (viol | force) & rvalid[:, None]
-        seq2 = ro[:, NDIR * self.pw] + eff.any(1).astype(_I32)
+        seq2 = ro[:, NDIR * pw] + eff.any(1).astype(_I32)
         ro2 = self._pack_out(
-            jnp.where(eff[..., None], pay, self._out_pay(ro)), seq2)
+            jnp.where(jnp.tile(eff, (1, pw)), pay, ro[:, :NDIR * pw]), seq2)
         st = st._replace(out=pl.put_peer(
             st.out, jnp.where(rvalid, rp, pd), ro2))
 
@@ -1142,7 +1145,7 @@ class JaxEngine:
         cand = back(eff & valid)        # (WW, NDIR) bool, window order
         b_origin, b_dest = back(s_origin), back(s_dest)
         b_edge, b_he = back(s_edge), back(s_he.astype(_U32))
-        b_pay = back(pay)               # (WW, NDIR, P)
+        b_pay = back(pay)               # (WW, 3P) payload columns
         b_seq = back(seq2)              # (WW,)
 
         # ---- wheel maintenance (lane-local): slip one cycle, shift
@@ -1211,10 +1214,9 @@ class JaxEngine:
             axis=1,
         ).reshape(Ln, WWl, roww)
         u = lambda a: a.reshape(-1).astype(_U32)
-        send_pay = b_pay.reshape(-1, self.pw)  # (3*WW, P)
         send_rows = jnp.stack(
             [u(b_origin), u(b_dest), u(b_edge), u(b_he)]
-            + [send_pay[:, c].astype(_U32) for c in range(self.pw)]
+            + [u(a) for a in P.comps(b_pay, pw)]
             + [u(bc(b_seq)), u(bc(b_seq))],
             axis=1,
         ).reshape(Ln, NDIR * WWl, roww)
@@ -1597,9 +1599,8 @@ class JaxEngine:
             mv = mv & ~st.dead[mp]  # crashed peers are silent — no sends
         kloc = knowledge(self.problem, st.inbox, st.x, st.x.shape[0])
         kmp = pl.take_peer_rep(kloc, mp)  # (2, P), replicated
-        pay = jnp.broadcast_to(kmp[:, None, :], (2, NDIR, pw))
         seq2 = pl.take_peer_rep(st.out, mp)[:, NDIR * pw] + 1
-        ro2 = self._pack_out(pay, seq2)
+        ro2 = self._pack_out(jnp.repeat(kmp, NDIR, axis=1), seq2)
         st = st._replace(out=pl.put_peer(
             st.out, jnp.where(mv, mp, pdg), ro2.astype(_I32)))
         dirs2 = jnp.broadcast_to(jnp.arange(NDIR, dtype=_I32)[None, :], (2, NDIR))
@@ -1610,7 +1611,8 @@ class JaxEngine:
         st = self._enqueue_events(
             st, (valid & bc2(mv)).reshape(-1), origin.reshape(-1),
             dest.reshape(-1), edge.reshape(-1), has_edge.reshape(-1),
-            pay.reshape(-1, pw), bc2(seq2).reshape(-1), alert=False,
+            [jnp.repeat(kmp[:, c], NDIR) for c in range(pw)],
+            bc2(seq2).reshape(-1), alert=False,
         )
 
         ap, adirs = P.alert_plan(jnp, pos_fix, pos_var)  # (6,), (6,)
@@ -1632,7 +1634,7 @@ class JaxEngine:
         zero6 = jnp.zeros(6, _U32)
         return self._enqueue_events(
             st, valid, origin, dest, edge, has_edge,
-            jnp.zeros((6, pw), _U32), zero6, alert=True,
+            [zero6] * pw, zero6, alert=True,
         )
 
     # -- engine API ----------------------------------------------------------
@@ -1688,6 +1690,13 @@ class JaxEngine:
         """(n,) cycle each peer's links last carried inbound traffic —
         the per-peer heartbeat `runtime.fault_tolerance` bridges from."""
         return np.asarray(self._st.heard).reshape(-1, NDIR)[: self.n].max(axis=1)
+
+    @property
+    def native_kernels(self) -> Tuple[str, ...]:
+        """The wheel kernels this engine compiles natively: the enabled
+        subset on a TPU, none elsewhere (enabled kernels then run in
+        interpret mode, the parity surface)."""
+        return () if self._wk_interp else tuple(sorted(self._wk))
 
     @property
     def deferral_rate(self) -> float:

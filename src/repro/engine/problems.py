@@ -81,36 +81,44 @@ class ThresholdProblem:
         return self.init_state(a[None, :])[0]
 
     # -- the decision rule ---------------------------------------------------
-    def margin(self, xp, pay: Array) -> Array:
-        """Signed distance of payload ``pay[..., :D+1]`` from the
-        threshold surface; output 1 iff margin(K) >= 0. Must be exact
-        (bit-equal) across numpy int64 and device int32/float32."""
+    # Each rule comes in column form: a payload is a sequence of its P
+    # component arrays (sum columns, then the count), all of one shape
+    # or broadcastable to one. The engines' row layout and the Pallas
+    # kernels' lane-dense planes (peers on the minor axis) then share
+    # one definition of the rule, and no rule needs an array constant
+    # or a gather — neither of which a TPU kernel body may hold.
+
+    def margin_cols(self, xp, cols) -> Array:
+        """Signed distance of the payload with component arrays `cols`
+        from the threshold surface; output 1 iff margin(K) >= 0. Must be
+        exact (bit-equal) across numpy int64 and device int32/float32."""
         raise NotImplementedError
 
-    def test(self, xp, agg: Array, k: Array) -> Tuple[Array, Array]:
-        """The safe-zone test: ``agg`` is the per-direction agreement
-        A = X_in + X_out (..., 3, P), ``k`` the knowledge (..., P).
-        Returns (send (..., 3) bool — margins of A and K - A disagree,
-        the Alg. 3 violation; output (...,) bool — margin(K) >= 0)."""
-        ta = self.margin(xp, agg)
-        tka = self.margin(xp, k[..., None, :] - agg)
+    def margin(self, xp, pay: Array) -> Array:
+        """`margin_cols` over a (..., P) payload array."""
+        return self.margin_cols(
+            xp, [pay[..., c] for c in range(self.payload_width)])
+
+    def test_cols(self, xp, agg, k) -> Tuple[Array, Array]:
+        """The safe-zone test in column form: `agg` holds the P
+        component arrays of the per-direction agreement A = X_in + X_out,
+        `k` those of the knowledge, broadcastable against `agg`.
+        Returns (send — margins of A and K - A disagree, the Alg. 3
+        violation, shaped like `agg`'s columns; output — margin(K) >= 0,
+        shaped like `k`'s columns)."""
+        ta = self.margin_cols(xp, agg)
+        tka = self.margin_cols(xp, [kc - ac for kc, ac in zip(k, agg)])
         send = ((ta >= 0) & (tka < 0)) | ((ta < 0) & (tka > 0))
-        return send, self.margin(xp, k) >= 0
+        return send, self.margin_cols(xp, k) >= 0
 
-    # -- kernel support ------------------------------------------------------
-    def test_consts(self, xp) -> Tuple[Array, ...]:
-        """Array constants `test` closes over (none for the linear
-        problems). Pallas kernel bodies may not capture array constants,
-        so the fused `threshold_step` kernel fetches these, passes them
-        in as explicit kernel inputs and routes them back through
-        `test_with_consts` — bit-identical to `test` by contract."""
-        return ()
-
-    def test_with_consts(self, xp, agg: Array, k: Array,
-                         consts: Tuple[Array, ...]) -> Tuple[Array, Array]:
-        """`test`, with the `test_consts` arrays supplied by the caller
-        (the default has none to thread through)."""
-        return self.test(xp, agg, k)
+    def test(self, xp, agg: Array, k: Array) -> Tuple[Array, Array]:
+        """`test_cols` over arrays: ``agg`` (..., 3, P), ``k`` (..., P).
+        Returns (send (..., 3) bool, output (...,) bool)."""
+        pw = self.payload_width
+        send, out = self.test_cols(
+            xp, [agg[..., c] for c in range(pw)],
+            [k[..., None, c] for c in range(pw)])
+        return send, out[..., 0]
 
     # -- convergence ---------------------------------------------------------
     def converged(self, xp, outputs: Array, truth: Array) -> Array:
@@ -147,8 +155,8 @@ class Majority(ThresholdProblem):
             raise ValueError("majority votes must be 0/1")
         return a
 
-    def margin(self, xp, pay: Array) -> Array:
-        return 2 * pay[..., 0] - pay[..., 1]
+    def margin_cols(self, xp, cols) -> Array:
+        return 2 * cols[0] - cols[1]
 
 
 class MeanMonitor(ThresholdProblem):
@@ -183,8 +191,8 @@ class MeanMonitor(ThresholdProblem):
             raise ValueError(f"mean data must be (n,) or (n, 1), got {a.shape}")
         return np.round(a * self.scale).astype(np.int64)
 
-    def margin(self, xp, pay: Array) -> Array:
-        return pay[..., 0] - self.T * pay[..., 1]
+    def margin_cols(self, xp, cols) -> Array:
+        return cols[0] - self.T * cols[1]
 
     def __repr__(self):
         return f"MeanMonitor(tau={self.tau}, scale={self.scale})"
@@ -259,26 +267,28 @@ class L2Thresh(ThresholdProblem):
                 f"l2 data must be (n, {self.data_width}), got {a.shape}")
         return np.round(a * self.scale).astype(np.int64)
 
-    def _proj(self, xp, pay: Array, U: Array = None) -> Array:
-        """(..., M) tangent-half-space margins f_m = <s, u_m> - T*c."""
-        if U is None:
-            U = xp.asarray(self.U)
-        acc = pay[..., 0].astype(xp.float32)[..., None] * U[:, 0]
-        for j in range(1, self.data_width):  # unrolled, fixed op order
-            acc = acc + pay[..., j].astype(xp.float32)[..., None] * U[:, j]
-        return acc - self.Tf * pay[..., self.data_width].astype(
-            xp.float32)[..., None]
+    def _proj(self, xp, cols):
+        """The M tangent-half-space margins f_m = <s, u_m> - T*c, one
+        array per direction. The cover's entries are float32 scalars
+        baked into the trace, and the sum is unrolled in a fixed order."""
+        d = self.data_width
+        f = [c.astype(xp.float32) for c in cols]
+        out = []
+        for u in self.U:
+            acc = f[0] * u[0]
+            for j in range(1, d):
+                acc = acc + f[j] * u[j]
+            out.append(acc - self.Tf * f[d])
+        return out
 
-    def margin(self, xp, pay: Array) -> Array:
-        return self._proj(xp, pay).max(-1)
+    def margin_cols(self, xp, cols) -> Array:
+        pk = self._proj(xp, cols)
+        best = pk[0]
+        for p in pk[1:]:
+            best = xp.maximum(best, p)
+        return best
 
-    def test_consts(self, xp):
-        return (xp.asarray(self.U),)
-
-    def test_with_consts(self, xp, agg: Array, k: Array, consts):
-        return self.test(xp, agg, k, U=consts[0])
-
-    def test(self, xp, agg: Array, k: Array, U: Array = None):
+    def test_cols(self, xp, agg, k):
         """Region-wise safe-zone test. Each tangent functional f_m is
         *linear and additive*, so the paper's quiescence argument holds
         per functional; the nonlinearity lives only in which functionals
@@ -300,17 +310,24 @@ class L2Thresh(ThresholdProblem):
         position, exhausted K - A in the second) behave exactly as in
         Alg. 3: empty agreements wake inside-deciding peers, exhausted
         residuals never re-violate (a symmetric region-membership test
-        storms there — observed)."""
-        pk = self._proj(xp, k, U)                  # (..., M)
-        out = pk.max(-1) >= 0
-        m_star = pk.argmax(-1)                     # (...,)
-        pa = self._proj(xp, agg, U)                # (..., 3, M)
-        pka = self._proj(xp, k[..., None, :] - agg, U)
-        viol_m = ((pa >= 0) & (pka < 0)) | ((pa < 0) & (pka > 0))
-        sel = m_star[..., None, None]
-        viol_out = xp.take_along_axis(viol_m, sel, -1)[..., 0]  # (..., 3)
-        send = xp.where(out[..., None], viol_out, viol_m.any(-1))
-        return send, out
+        storms there — observed).
+
+        m* is the FIRST maximal direction (argmax order), tracked by a
+        strict running comparison; bools combine by mask algebra, not
+        selects."""
+        pk = self._proj(xp, k)
+        pa = self._proj(xp, agg)
+        pka = self._proj(xp, [kc - ac for kc, ac in zip(k, agg)])
+        viol = [((a >= 0) & (r < 0)) | ((a < 0) & (r > 0))
+                for a, r in zip(pa, pka)]
+        best, sel, anyv = pk[0], viol[0], viol[0]
+        for p, v in zip(pk[1:], viol[1:]):
+            better = p > best
+            best = xp.maximum(best, p)
+            sel = (better & v) | (~better & sel)
+            anyv = anyv | v
+        out = best >= 0
+        return (out & sel) | (~out & anyv), out
 
     def __repr__(self):
         return (f"L2Thresh(tau={self.tau}, dim={self.data_width}, "

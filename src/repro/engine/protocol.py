@@ -110,7 +110,9 @@ def deliver_rules(xp, *, origin: Array, dest: Array, edge: Array,
     root_wrap = (
         (pos_i == 0) & (dest > max_addr) if repair else xp.zeros_like(at_pos)
     )
-    step_cw = xp.where(root_wrap, False, xp.where(self_seg, in_cw, ~in_cw))
+    # bool mask algebra, not selects (Pallas TPU kernels trace this
+    # body and cannot select bools): ~root_wrap & (self_seg == in_cw)
+    step_cw = ~root_wrap & ~(self_seg ^ in_cw)
     new_dest = xp.where(
         going_up, A.up(dest, d),
         xp.where(step_cw, A.cw(dest, d), A.ccw(dest, d)),
@@ -200,34 +202,65 @@ def suspicion_rules(xp, heard: Array, probed: Array, t: Array,
 # Alg. 3 — threshold algebra (knowledge / agreement / violation / Send)
 # ---------------------------------------------------------------------------
 
+def comp(c: int) -> slice:
+    """The columns of payload component c in the component-major
+    (..., 3P) payload layout: column c*3 + v holds component c of
+    direction v. The one statement of that layout — the device engine's
+    payload columns, these rules and the `threshold_step` kernel's
+    planes (rows there) all index through it."""
+    return slice(c * 3, (c + 1) * 3)
+
+
+def comps(cols: Array, pw: int) -> list:
+    """(..., 3P) payload columns -> P per-component (..., 3) blocks."""
+    return [cols[..., comp(c)] for c in range(pw)]
+
+
 def thr2(ones: Array, total: Array) -> Array:
     """2 * thr(X): integer-exact sign of ones - total/2 (the paper's
     (1,-1/2)^t X functional, kept in integers)."""
     return 2 * ones - total
 
 
-def threshold_rules(problem, xp, in_pay: Array, out_pay: Array,
-                    x: Array) -> Tuple[Array, Array, Array]:
+def threshold_rules_cols(problem, xp, in_cols: Array, out_cols: Array,
+                         x: Array) -> Tuple[Array, Array, Array]:
     """The complete per-peer safe-zone test for ANY `ThresholdProblem`
-    (`repro.engine.problems`), vectorized over peers.
+    (`repro.engine.problems`), vectorized over peers, on payload
+    columns.
 
-    ``in_pay`` / ``out_pay`` are the (..., 3, P) received/sent payload
-    planes (P = D + 1: vector-sum columns then the count column) and
-    ``x`` the (..., D) own data. Returns (viol (..., 3) bool,
-    output (...,) int, pay (..., 3, P)) where pay = K - X_in is the
-    Send(v) payload restoring agreement A_{i,v} = K_i.
+    ``in_cols`` / ``out_cols`` are the (..., 3P) received/sent payloads,
+    component-major: column c*3 + v holds component c (P = D + 1:
+    vector-sum components then the count) of direction v. ``x`` is the
+    (..., D) own data. Returns (viol (..., 3) bool, output (...,) int,
+    pay (..., 3P)) where pay = K - X_in is the Send(v) payload restoring
+    agreement A_{i,v} = K_i, in the same column layout.
 
     The Alg. 3 majority algebra is `problem=Majority()`; every step of
     this function then reduces to `majority_rules` bit for bit (pinned
     by tests). Pure arithmetic + the problem's margin — jit-safe, no
     data-dependent control flow.
     """
-    one = xp.ones_like(x[..., :1])
-    k = in_pay.sum(-2) + xp.concatenate([x, one], axis=-1)  # (..., P)
-    agg = in_pay + out_pay  # (..., 3, P)
-    viol, output = problem.test(xp, agg, k)
-    pay = k[..., None, :] - in_pay
-    return viol, output.astype(in_pay.dtype), pay
+    pw = problem.payload_width
+    ic = comps(in_cols, pw)                                      # (..., 3)
+    own = [x[..., c:c + 1] for c in range(pw - 1)]
+    own.append(xp.ones_like(x[..., :1]))
+    k = [a[..., 0:1] + a[..., 1:2] + a[..., 2:3] + o
+         for a, o in zip(ic, own)]                               # (..., 1)
+    agg = [a + o for a, o in zip(ic, comps(out_cols, pw))]
+    viol, output = problem.test_cols(xp, agg, k)
+    pay = xp.concatenate([kc - a for kc, a in zip(k, ic)], axis=-1)
+    return viol, output[..., 0].astype(in_cols.dtype), pay
+
+
+def threshold_rules(problem, xp, in_pay: Array, out_pay: Array,
+                    x: Array) -> Tuple[Array, Array, Array]:
+    """`threshold_rules_cols` over (..., 3, P) received/sent payload
+    arrays (the numpy engine's layout); pay comes back as (..., 3, P)."""
+    pw = problem.payload_width
+    cols = lambda a: xp.concatenate([a[..., c] for c in range(pw)], axis=-1)
+    viol, output, pay = threshold_rules_cols(
+        problem, xp, cols(in_pay), cols(out_pay), x)
+    return viol, output, xp.stack(comps(pay, pw), axis=-1)
 
 
 def majority_rules(in_ones: Array, in_tot: Array, out_ones: Array,

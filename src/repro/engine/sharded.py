@@ -74,10 +74,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from repro.core.dht import Ring
-from repro.core.tree_collectives import shard_map
 from repro.engine.jax_backend import (DeviceState, JaxEngine, NDIR, PeerPlane,
                                       _I32)
 

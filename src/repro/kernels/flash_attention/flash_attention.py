@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -140,7 +138,7 @@ def flash_attention_fwd(
     )
     compiler_params = None
     if not interpret:
-        compiler_params = CompilerParams(
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
     out = pl.pallas_call(

@@ -15,13 +15,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
+
 from .flash_attention import flash_attention_fwd
 from .ref import mha_reference
 from .xla_ref import flash_attention_xla
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def flash_attention(
@@ -35,7 +33,7 @@ def flash_attention(
     """(B,Hq,Sq,Dh) x (B,Hkv,Skv,Dh) -> (B,Hq,Sq,Dhv). Differentiable."""
     sq, skv = q.shape[2], k.shape[2]
     aligned = sq % 128 == 0 and skv % 128 == 0
-    if use_kernel and _on_tpu() and aligned:
+    if use_kernel and on_tpu() and aligned:
         return _pallas_path(q, k, v, causal, window, scale, q_offset)
     if sq * skv >= 128 * 128 and skv % 16 == 0:
         return flash_attention_xla(q, k, v, causal, window, scale, q_offset)
@@ -47,7 +45,7 @@ def flash_attention(
 def _pallas_path(q, k, v, causal, window, scale, q_offset):
     return flash_attention_fwd(q, k, v, causal=causal, window=window,
                                scale=scale, q_offset=q_offset,
-                               interpret=not _on_tpu())
+                               interpret=not on_tpu())
 
 
 def _pl_fwd(q, k, v, causal, window, scale, q_offset):

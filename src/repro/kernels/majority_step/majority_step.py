@@ -18,7 +18,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _maj_kernel(in_ones_ref, in_tot_ref, out_ones_ref, out_tot_ref, x_ref,
@@ -61,7 +61,7 @@ def majority_step_kernel(
 
     compiler_params = None
     if not interpret:
-        compiler_params = CompilerParams(
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         )
     spec3 = pl.BlockSpec((3, block), lambda i: (0, i))

@@ -3,15 +3,12 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
+
+from repro.kernels import on_tpu
 
 from .majority_step import majority_step_kernel
 from .ref import majority_step_reference
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def majority_step(
@@ -20,7 +17,7 @@ def majority_step(
     """(viol (N,3) bool, output (N,), pay_ones (N,3), pay_tot (N,3))."""
     if use_kernel and x.shape[0] >= 8:
         return majority_step_kernel(
-            in_ones, in_tot, out_ones, out_tot, x, interpret=not _on_tpu()
+            in_ones, in_tot, out_ones, out_tot, x, interpret=not on_tpu()
         )
     viol, out, po, pt = majority_step_reference(
         jnp.asarray(in_ones, jnp.int32), jnp.asarray(in_tot, jnp.int32),

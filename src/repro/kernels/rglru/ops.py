@@ -15,18 +15,16 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
+
 from .ref import linear_scan_reference
 from .rglru import rglru_scan
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _scan(a, u, h0, use_kernel):
     t, w = a.shape[1], a.shape[2]
     if use_kernel and t >= 8 and w >= 8:
-        return rglru_scan(a, u, h0, interpret=not _on_tpu())
+        return rglru_scan(a, u, h0, interpret=not on_tpu())
     return linear_scan_reference(a, u, h0)
 
 

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _rglru_kernel(a_ref, u_ref, h0_ref, h_ref, hlast_ref, state_scr,
                   *, block_t: int, nt: int):
@@ -76,7 +74,7 @@ def rglru_scan(
     kernel = functools.partial(_rglru_kernel, block_t=block_t, nt=nt)
     compiler_params = None
     if not interpret:
-        compiler_params = CompilerParams(
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
     h, hlast = pl.pallas_call(

@@ -3,15 +3,12 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
+
+from repro.kernels import on_tpu
 
 from .ref import threshold_gate_reference
 from .threshold_gate import threshold_gate_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def threshold_gate(
@@ -24,5 +21,5 @@ def threshold_gate(
     tau = jnp.asarray(tau, jnp.float32)
     if use_kernel and grad.size >= 8:
         return threshold_gate_kernel(grad, residual, tau,
-                                     interpret=not _on_tpu())
+                                     interpret=not on_tpu())
     return threshold_gate_reference(grad, residual, tau)

@@ -20,7 +20,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels._compat import CompilerParams, MemorySpace
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _tg_kernel(tau_ref, g_ref, r_ref, send_ref, newres_ref, cnt_ref):
@@ -55,14 +55,14 @@ def threshold_gate_kernel(
 
     compiler_params = None
     if not interpret:
-        compiler_params = CompilerParams(
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         )
     send, newres, cnt = pl.pallas_call(
         _tg_kernel,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec(memory_space=MemorySpace.SMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM),
             pl.BlockSpec((block,), lambda i: (i,)),
             pl.BlockSpec((block,), lambda i: (i,)),
         ],

@@ -15,7 +15,7 @@ tests/test_kernels.py in interpret mode on CPU CI):
     kernel (per-block while_loop over `protocol.deliver_rules`);
   * `threshold_step`  — problem-generic fused margin/test/Send
     payloads, parameterized by payload width P (traces the problem's
-    own `test` inside the kernel body).
+    own column-form `test_cols` inside the kernel body).
 
 The engine (`engine.jax_backend`) wires these into the cycle body
 behind the `PeerPlane` layer. Every kernel operates on SHARD-LOCAL

@@ -11,13 +11,13 @@ early instead of riding the global worst case.
 
 The loop body is `protocol.deliver_rules` traced with `xp = jnp` inside
 the kernel (the same addressing bit algebra both backends share), so
-parity against `descent_reference` — a verbatim mirror of
-`deliver_network_step`, pinned equal to it by tests — is by
-construction: identical ops on identical values. Rows whose block
+parity against `descent_reference` — the loop `deliver_network_step`
+runs — is by construction: identical ops on identical values. Rows whose block
 terminates are masked, exactly like the reference's global live mask.
 
-Bools cross the kernel boundary as int32 (TPU-stable); addresses stay
-uint32 throughout.
+Bools cross the kernel boundary as int32, and the loop carries its
+masks as int32 too (Mosaic neither carries nor selects bool vectors);
+addresses stay uint32 throughout.
 """
 from __future__ import annotations
 
@@ -36,13 +36,21 @@ def _descent_loop(origin, dest, edge, has_edge, live, entry, pos_i, a_prev,
                   a_self, self_seg, max_addr, d: int):
     """The shared while_loop body — called by both the reference (full
     width) and the kernel (per block). Returns (acc, drop, o_dest,
-    o_edge, o_he) with the exact `deliver_network_step` semantics."""
+    o_edge, o_he) with the exact `deliver_network_step` semantics.
+
+    The loop carries its masks as int32 0/1: Mosaic cannot carry bool
+    vectors through a while loop, and the values are the same either
+    way."""
+    b = lambda m: m != 0
+    i = lambda m: m.astype(_I32)
+
     def cond(c):
-        return c[0].any()
+        return b(c[0]).any()
 
     def body(c):
         (lv, ent, cur_dest, cur_edge, cur_he,
          acc, drop, o_dest, o_edge, o_he) = c
+        lv, ent, cur_he = b(lv), b(ent), b(cur_he)
         dlv = proto.deliver_rules(
             jnp, origin=origin, dest=cur_dest, edge=cur_edge,
             has_edge=cur_he, network_entry=ent, pos_i=pos_i,
@@ -55,29 +63,28 @@ def _descent_loop(origin, dest, edge, has_edge, live, entry, pos_i, a_prev,
         stay = moving & in_segment(dlv.new_dest, a_prev, a_self)
         fwd = moving & ~stay
         return (
-            stay, ent & ~stay,
+            i(stay), i(ent & ~stay),
             jnp.where(stay, dlv.new_dest, cur_dest),
             jnp.where(stay, dlv.new_edge, cur_edge),
-            jnp.where(stay, dlv.new_has_edge, cur_he),
-            acc | now_acc, drop | now_drop,
+            jnp.where(stay, i(dlv.new_has_edge), i(cur_he)),
+            acc | i(now_acc), drop | i(now_drop),
             jnp.where(fwd, dlv.new_dest, o_dest),
             jnp.where(fwd, dlv.new_edge, o_edge),
-            jnp.where(fwd, dlv.new_has_edge, o_he),
+            jnp.where(fwd, i(dlv.new_has_edge), o_he),
         )
 
-    false_b = jnp.zeros(live.shape, bool)
-    init = (live, entry, dest, edge, has_edge,
-            false_b, false_b, dest, edge, has_edge)
+    zero = jnp.zeros(live.shape, _I32)
+    init = (i(live), i(entry), dest, edge, i(has_edge),
+            zero, zero, dest, edge, i(has_edge))
     (_, _, _, _, _, acc, drop, o_dest, o_edge, o_he) = jax.lax.while_loop(
         cond, body, init)
-    return acc, drop, o_dest, o_edge, o_he
+    return b(acc), b(drop), o_dest, o_edge, b(o_he)
 
 
 def descent_reference(origin, dest, edge, has_edge, live, entry, pos_i,
                       a_prev, a_self, self_seg, max_addr, d: int):
     """XLA-path reference: one global while_loop over the whole batch —
-    a verbatim mirror of `jax_backend.deliver_network_step` (pinned
-    equal by tests/test_kernels.py)."""
+    the loop `jax_backend.deliver_network_step` runs."""
     return _descent_loop(origin, dest, edge, has_edge, live, entry, pos_i,
                          a_prev, a_self, self_seg, max_addr, d)
 

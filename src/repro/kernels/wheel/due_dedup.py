@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.wheel._common import on_tpu, pad_to
 
 _I32 = jnp.int32
@@ -144,7 +144,7 @@ def due_dedup_kernel(flat, acc_d, acc_a, w_seq, link_seq,
     shp1 = jax.ShapeDtypeStruct((wwp, 1), _I32)
     compiler_params = None
     if not interpret:
-        compiler_params = CompilerParams(
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
     (best, abest, rep, aforce, win, lose, fresh, aw, isrep) = pl.pallas_call(
         kern,

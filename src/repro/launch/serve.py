@@ -119,7 +119,9 @@ class DecisionNotifier:
     """
 
     def __init__(self):
-        self._last: Dict[int, int] = {}
+        # last published snapshot, addresses ascending
+        self._last_addrs = np.zeros(0, np.uint64)
+        self._last_outs = np.zeros(0, np.int64)
         self._subs: Dict[int, Callable[[Transition], None]] = {}
         self._next_sub = 0
         self.published = 0   # transitions emitted
@@ -142,15 +144,26 @@ class DecisionNotifier:
                 outputs: np.ndarray) -> List[Transition]:
         """Diff the snapshot against the last published outputs; emit
         and deliver the transitions. New addresses (joiners) transition
-        to their first output; departed addresses are pruned."""
-        cur = {int(a): int(o) for a, o in zip(addrs, outputs)}
-        changed: Dict[int, List[int]] = {}
-        for a, o in cur.items():
-            if self._last.get(a) != o:
-                changed.setdefault(o, []).append(a)
-        self._last = cur
-        out = [Transition(int(t), frozenset(peers), o)
-               for o, peers in sorted(changed.items())]
+        to their first output; departed addresses are pruned. The diff
+        is vectorized: O(n) numpy per window, so a million-peer overlay
+        does not pay a Python loop over every peer."""
+        a = np.asarray(addrs).astype(np.uint64)
+        o = np.asarray(outputs).astype(np.int64)
+        if a.size > 1 and not (a[1:] > a[:-1]).all():
+            order = np.argsort(a, kind="stable")
+            a, o = a[order], o[order]
+        la, lo = self._last_addrs, self._last_outs
+        if la.shape == a.shape and (la == a).all():  # no churn since
+            changed = lo != o
+        elif la.size:
+            j = np.minimum(np.searchsorted(la, a), la.size - 1)
+            changed = (la[j] != a) | (lo[j] != o)
+        else:
+            changed = np.ones(a.size, bool)
+        self._last_addrs, self._last_outs = a, o
+        out = [Transition(int(t), frozenset(a[changed & (o == v)].tolist()),
+                          int(v))
+               for v in np.unique(o[changed])]
         for tr in out:
             self.published += 1
             for cb in list(self._subs.values()):

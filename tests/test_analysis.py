@@ -64,7 +64,7 @@ def test_collective_bytes_sees_psum():
         import warnings; warnings.simplefilter("ignore")
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.core.tree_collectives import shard_map
+        from jax import shard_map
         from repro.analysis.hlo import collective_bytes
         mesh = jax.make_mesh((4,), ("d",))
         f = shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,

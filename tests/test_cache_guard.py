@@ -1,19 +1,22 @@
-"""Regression tests for the persistent-XLA-cache validation guard
-(`benchmarks.run.validate_cache_dir`).
+"""Regression tests for the persistent-XLA-cache placement and its
+validation guard (`benchmarks.run.enable_compilation_cache`,
+`validate_cache_dir`).
 
 The scar (PR 8): a results/.jax_cache serialized against an older
 jaxlib/engine deserialized into poisoned executables that hung
-armed-engine runs roughly 1-in-3. The guard keys the cache dir on
-(jaxlib version, ENGINE_SCHEMA, CPU runtime regime) via a CACHE_KEY
-marker file and clears anything that does not match — these tests pin
-every branch of that decision, including the original poisoned-dir
-shape (entries but no marker)."""
+armed-engine runs roughly 1-in-3. The guard keys the cache dir it
+creates on (jaxlib version, ENGINE_SCHEMA) via a CACHE_KEY marker file
+and clears its own dir when the key changes. It never deletes entries
+in a directory it did not create (no marker), and a directory placed
+from outside through ``JAX_COMPILATION_CACHE_DIR`` is used as is and
+never touched. Without the variable the cache path is anchored on the
+repo root, so it is the same from any cwd."""
 from __future__ import annotations
 
 import os
 
-from benchmarks.run import (CACHE_KEY_FILE, cache_key, enable_compilation_cache,
-                            validate_cache_dir)
+from benchmarks.run import (CACHE_DIR, CACHE_KEY_FILE, cache_key,
+                            enable_compilation_cache, validate_cache_dir)
 
 
 def _fill(d, names=("entry_a.bin", "entry_b.bin")):
@@ -61,7 +64,7 @@ def test_matching_marker_preserves_entries(tmp_path):
 def test_stale_marker_clears_dir(tmp_path):
     """The direct scar shape: entries written under an older key."""
     d = str(tmp_path / "cache")
-    validate_cache_dir(d, key="jaxlib=0.4.0;engine_schema=9;cpu_thunk=off")
+    validate_cache_dir(d, key="jaxlib=0.4.0;engine_schema=9")
     names = _fill(d)
     assert validate_cache_dir(d, key=cache_key()) == "cleared"
     for name in names:
@@ -73,27 +76,54 @@ def test_stale_marker_clears_dir(tmp_path):
 
 
 def test_unmarked_nonempty_dir_clears(tmp_path):
-    """Pre-guard cache dirs have entries but no marker — provenance
-    unknown, so they must be treated as poisoned, not grandfathered."""
+    """A non-empty dir without a marker was not created by the guard:
+    its provenance is unknown, so the guard refuses it ("foreign") —
+    it neither clears nor marks it, and the caller must not use it."""
     d = str(tmp_path / "cache")
     names = _fill(d)
-    assert validate_cache_dir(d, key="k1") == "cleared"
+    assert validate_cache_dir(d, key="k1") == "foreign"
     for name in names:
-        assert not os.path.exists(os.path.join(d, name))
-    assert os.path.exists(_marker(d))
+        assert os.path.exists(os.path.join(d, name))
+    assert not os.path.exists(_marker(d))
 
 
 def test_enable_compilation_cache_validates(tmp_path, monkeypatch):
-    """End to end: enable_compilation_cache on a poisoned dir (stale
-    marker + entries) must clear it before handing it to jax."""
+    """A cache dir placed from outside (JAX_COMPILATION_CACHE_DIR) is
+    used as is: even a stale marker with entries is left untouched, and
+    no directory is set in code."""
     d = str(tmp_path / "cache")
     validate_cache_dir(d, key="stale-key")
     _fill(d)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
-    enable_compilation_cache()
-    assert not os.path.exists(os.path.join(d, "entry_a.bin"))
-    with open(_marker(d)) as f:
-        assert f.read().strip() == cache_key()
     import jax
 
-    assert jax.config.jax_compilation_cache_dir == os.path.abspath(d)
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compilation_cache() == d
+    assert os.path.exists(os.path.join(d, "entry_a.bin"))
+    with open(_marker(d)) as f:
+        assert f.read().strip() == "stale-key"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_default_cache_dir_is_anchored_on_repo_root():
+    """Without the variable the cache path does not depend on the cwd:
+    it is the git-ignored results/.jax_cache under the repo root."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert CACHE_DIR == os.path.join(repo, "results", ".jax_cache")
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert "results/.jax_cache/" in f.read().split()
+
+
+def test_enable_compilation_cache_skips_foreign_dir(tmp_path, monkeypatch):
+    """Without the variable, a foreign dir at the cache path is neither
+    cleared nor used: the cache stays off."""
+    d = str(tmp_path / "cache")
+    names = _fill(d)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compilation_cache(cache_dir=d) is None
+    for name in names:
+        assert os.path.exists(os.path.join(d, name))
+    assert jax.config.jax_compilation_cache_dir == before

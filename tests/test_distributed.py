@@ -65,8 +65,9 @@ _COLLECTIVE_SCRIPT = textwrap.dedent("""
     import jax, numpy as np
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
+    from jax import shard_map as sm
     from repro.core.tree_collectives import (
-        tree_all_reduce, tree_broadcast, tree_reduce, shard_map as sm)
+        tree_all_reduce, tree_broadcast, tree_reduce)
     mesh = jax.make_mesh((8,), ("pod",))
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((16, 4)), jnp.float32)

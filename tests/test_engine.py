@@ -73,6 +73,56 @@ def test_majority_rules_numpy_vs_jnp():
                                       np.asarray(b, np.int64))
 
 
+@pytest.mark.parametrize("m,seed", [(1, 0), (1000, 1), (4096, 2)])
+def test_group_ranks_sort_and_scan_agree(m, seed):
+    """The wheel's append ranks: the sort form (off the TPU) and the
+    masked-cumsum form (compiled for the TPU) give one exact result,
+    a plain per-group counter over the live rows."""
+    from repro.engine.jax_backend import JaxEngine
+
+    rng = np.random.default_rng(seed)
+    ng = 88
+    g = rng.integers(0, ng, m)
+    live = rng.random(m) < 0.6
+    want_rank = np.zeros(m, np.int64)
+    want_counts = np.zeros(ng, np.int64)
+    for i in np.flatnonzero(live):
+        want_rank[i] = want_counts[g[i]]
+        want_counts[g[i]] += 1
+    for scan in (False, True):
+        rank, counts = JaxEngine._group_ranks(
+            jnp.asarray(g, jnp.int32), jnp.asarray(live), ng, scan=scan)
+        np.testing.assert_array_equal(np.asarray(rank), want_rank)
+        np.testing.assert_array_equal(np.asarray(counts), want_counts)
+
+
+def test_jax_engine_same_run_under_either_rank_form(monkeypatch):
+    """A whole device-engine run is the same with the TPU's rank form
+    forced on the CPU: outputs, cycles, messages and drops."""
+    from functools import partial
+
+    from repro.engine.jax_backend import JaxEngine
+
+    ring = Ring.random(300, 32, seed=4)
+    votes = _votes(300, 0.4, np.random.default_rng(4))
+
+    def run():
+        eng = make_engine("jax", ring, votes, seed=5)
+        eng.step(80)
+        return (np.asarray(eng.outputs()), eng.t, eng.messages_sent,
+                eng.dropped)
+
+    base = run()
+    calls = []
+    scan = partial(JaxEngine._group_ranks, scan=True)
+    monkeypatch.setattr(JaxEngine, "_group_ranks", staticmethod(
+        lambda *a, **k: calls.append(1) or scan(*a, **k)))
+    forced = run()
+    assert calls, "the forced rank form was never traced"
+    np.testing.assert_array_equal(forced[0], base[0])
+    assert forced[1:] == base[1:]
+
+
 # ---------------------------------------------------------------------------
 # 2. step level — numpy step_batch vs the jax engine's delivery loop
 # ---------------------------------------------------------------------------

@@ -56,26 +56,53 @@ def test_threshold_step_matches_rules(problem, dw, n):
     in_pay = jnp.asarray(rng.integers(-40, 41, (n, NDIR, pw)), jnp.int32)
     out_pay = jnp.asarray(rng.integers(-40, 41, (n, NDIR, pw)), jnp.int32)
     x = jnp.asarray(rng.integers(-300, 301, (n, p.data_width)), jnp.int32)
+    # the engine's payload columns: column c*3 + v = component c, dir v
+    cols = lambda a: jnp.concatenate([a[..., c] for c in range(pw)], -1)
     want = proto.threshold_rules(p, jnp, in_pay, out_pay, x)
-    got = threshold_step_kernel(p, in_pay, out_pay, x, block=256,
-                                interpret=True)
-    for w, g, name in zip(want, got, ("viol", "out", "pay")):
+    got = threshold_step_kernel(p, cols(in_pay), cols(out_pay), x,
+                                block=256, interpret=True)
+    for w, g, name in zip(want[:2], got[:2], ("viol", "out")):
         _eq(g, w, f"{problem} {name}")
+    _eq(got[2], cols(want[2]), f"{problem} pay")
 
 
 def test_threshold_step_l2_consts_roundtrip():
-    """L2's direction cover rides as an explicit kernel input
-    (test_consts); test_with_consts must reproduce test() exactly."""
+    """L2's direction cover is baked into the trace as float32 scalars
+    (a TPU kernel body may hold no array constant and no gather). The
+    unrolled column-form test must reproduce the matrix form of the
+    rule — projections against the cover as an array, argmax, gather —
+    exactly, on both the engines' row layout and the kernels'
+    lane-dense planes."""
     p = get_problem("l2")
     rng = np.random.default_rng(0)
-    agg = jnp.asarray(rng.integers(-500, 501, (33, NDIR, 3)), jnp.int32)
-    k = jnp.asarray(rng.integers(-500, 501, (33, 3)), jnp.int32)
-    consts = tuple(p.test_consts(jnp))
-    assert len(consts) == 1 and consts[0].shape == p.U.shape
-    want = p.test(jnp, agg, k)
-    got = p.test_with_consts(jnp, agg, k, consts)
-    _eq(got[0], want[0])
-    _eq(got[1], want[1])
+    agg = rng.integers(-500, 501, (33, NDIR, 3)).astype(np.int32)
+    k = rng.integers(-500, 501, (33, 3)).astype(np.int32)
+    # can be hit exactly: a zero payload sits on every face of the cover
+    agg[0] = 0
+    k[0] = 0
+
+    def proj(pay):
+        f = pay.astype(np.float32)
+        acc = f[..., 0, None] * p.U[:, 0]
+        for j in range(1, p.data_width):
+            acc = acc + f[..., j, None] * p.U[:, j]
+        return acc - p.Tf * f[..., p.data_width, None]
+
+    pk = proj(k)
+    out = pk.max(-1) >= 0
+    pa, pka = proj(agg), proj(k[:, None, :] - agg)
+    viol_m = ((pa >= 0) & (pka < 0)) | ((pa < 0) & (pka > 0))
+    sel = np.take_along_axis(viol_m, pk.argmax(-1)[:, None, None], -1)[..., 0]
+    send = np.where(out[:, None], sel, viol_m.any(-1))
+    for xp in (np, jnp):
+        got = p.test(xp, xp.asarray(agg), xp.asarray(k))
+        _eq(got[0], send)
+        _eq(got[1], out)
+    cols = p.test_cols(
+        jnp, [jnp.asarray(agg[..., c].T) for c in range(3)],
+        [jnp.asarray(k[None, :, c]) for c in range(3)])
+    _eq(cols[0], send.T)
+    _eq(cols[1][0], out)
 
 
 # -- due_dedup: window-local winner/representative/force election ---------

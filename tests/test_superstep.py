@@ -274,9 +274,10 @@ def test_run_smoke_xla_cache_warm(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = repo + os.pathsep + os.path.join(repo, "src")
     env["JAX_PLATFORMS"] = "cpu"
-    # the run must use its own cache under tmp_path (run.py respects an
-    # inherited cache dir, e.g. on CI)
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    # the run must use its own cache under tmp_path, placed from outside
+    # the way CI and the chip machine place theirs
+    cache = tmp_path / "results" / ".jax_cache"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache)
 
     def smoke():
         r = subprocess.run(
@@ -291,7 +292,6 @@ def test_run_smoke_xla_cache_warm(tmp_path):
         assert m, r.stdout
         return float(m.group(1))
 
-    cache = tmp_path / "results" / ".jax_cache"
     cold_setup = smoke()
     entries = set(os.listdir(cache))
     assert entries, "first --smoke left no persistent XLA cache entries"

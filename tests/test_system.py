@@ -8,14 +8,15 @@ import numpy as np
 import pytest
 
 
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+
+
 def _run(args, timeout=560):
     env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src")
-    )
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
     env.pop("XLA_FLAGS", None)
     return subprocess.run([sys.executable, "-m"] + args, capture_output=True,
-                          text=True, env=env, timeout=timeout)
+                          text=True, env=env, timeout=timeout, cwd=ROOT)
 
 
 @pytest.mark.slow
@@ -48,13 +49,11 @@ def test_threshold_sync_trainer():
 
 @pytest.mark.slow
 def test_serve_smoke():
-    r = _run([
-        "repro.launch.serve", "--arch", "smollm-135m", "--smoke",
-        "--requests", "4", "--slots", "2", "--max-new", "4",
-        "--prompt-len", "8", "--cache-len", "32",
-    ])
+    """The serve demo CLI end to end on the device engine: an
+    open-loop update stream against a live 256-peer overlay."""
+    r = _run(["repro.launch.serve", "--backend", "jax", "--n", "256"])
     assert r.returncode == 0, r.stderr[-2000:]
-    assert "served 4 requests" in r.stdout
+    assert "[serve] dropped = 0" in r.stdout, r.stdout[-2000:]
 
 
 @pytest.mark.slow
