@@ -1,0 +1,139 @@
+"""The comparison that decides `correct`, driven through a whole run at
+a size a test can hold (on the CPU, past the harness's look for a
+chip): a sound run is correct, and the control and every fault the
+cells can have come out not correct."""
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import control
+from harness import device, runner, spec
+
+TINY = {
+    "mainline-512k-majority.coldstart": {
+        "n": 2000, "engine": {"capacity_per_peer": 8, "pad_to": 4096,
+                              "work_budget": 1024}},
+    "mainline-64k-mean.steady": {
+        "n": 2000, "engine": {"capacity_per_peer": 8, "pad_to": 4096}},
+}
+
+
+@pytest.fixture(autouse=True)
+def no_chip_look(monkeypatch):
+    monkeypatch.setattr(device, "require_tpu", lambda chips: {
+        "platform": "cpu", "kind": "TPU v5 lite", "count": chips})
+    monkeypatch.setattr(device, "peak_bytes", lambda chips: 0)
+    monkeypatch.setattr(device, "enable_cache", lambda: None)
+
+
+# the drain at the tiny size: a sound run settles well inside it
+DRAIN_CAP_S = 30
+
+
+def tiny(name, **engine):
+    cell = spec.load_cell(name)
+    cfg = dict(cell.config)
+    over = TINY[name]
+    cfg["n"] = over["n"]
+    cfg["engine"] = dict(cfg["engine"], **over["engine"], **engine)
+    return cell._replace(config=cfg, traffic=dict(
+        cell.traffic, warm_batch_max=8, drain_cap_s=DRAIN_CAP_S))
+
+
+def run(cell, faults=None, seed=2**33 + 7):
+    return runner.run(cell, seed, 2.0, False, time.perf_counter(),
+                      faults=faults)
+
+
+def failing(result):
+    return {k for k, v in result["checks"].items()
+            if v["value"] > v["limit"]}
+
+
+def state_unchanged(win):
+    win.engine.step = lambda cycles=1: None
+
+
+def half_batch(win):
+    apply = win.engine.apply_coalesced
+
+    def half(idx, vals):
+        k = len(idx) // 2
+        return apply(idx[:k], vals[:k])
+    win.engine.apply_coalesced = half
+
+
+def answer_altered(win):
+    outputs = win.engine.outputs
+
+    def flipped():
+        out = np.array(outputs())
+        out[0] ^= 1
+        return out
+    win.engine.outputs = flipped
+
+
+def wrong_election(win):
+    """The due-scan election delivers the oldest row of each link and
+    consumes the others without deferring them: no row is dropped and
+    conservation holds, but a newer payload is lost. Put in place of
+    the election the engine calls (`due_dedup`), whose plane form
+    answers the same question on the CPU; the programs are traced
+    again with it."""
+    from repro.engine import jax_backend
+    from repro.kernels.wheel.due_dedup import due_dedup_reference
+
+    def oldest_wins(flat, acc_d, acc_a, w_seq, link_seq, nl, **_):
+        winner, loser, fresh, alert_write, is_rep, aforce = \
+            due_dedup_reference(flat, acc_d, acc_a, w_seq, link_seq, nl)
+        ww = flat.shape[0]
+        wi = jnp.arange(ww, dtype=jnp.int32)
+        first = jnp.full(nl, ww, jnp.int32).at[
+            jnp.where(acc_d, flat, nl)].min(jnp.where(acc_d, wi, ww),
+                                            mode="drop")
+        oldest = acc_d & (wi == first[flat])
+        return (oldest, jnp.zeros_like(loser), oldest & (fresh | loser),
+                alert_write, is_rep, aforce)
+
+    jax_backend.due_dedup = oldest_wins
+    jax.clear_caches()
+
+
+# the election runs where the engine calls `due_dedup`
+wrong_election.engine = {"kernel": "pallas", "wheel_kernels": ["dedup"]}
+
+
+@pytest.mark.parametrize("name", sorted(TINY))
+def test_sound_run_is_correct(name):
+    res = run(tiny(name))
+    assert res["correct"], res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+
+
+@pytest.mark.parametrize("name", sorted(TINY))
+def test_control_is_not_correct(name):
+    res = run(control.with_control(tiny(name)))
+    assert not res["correct"]
+    assert failing(res)
+
+
+CASES = [(name, fault) for name in sorted(TINY)
+         for fault in (state_unchanged, answer_altered)]
+# the storm is where links collide within a cycle, so where the election
+# decides; a flush is where a batch can be cut
+CASES.append(("mainline-512k-majority.coldstart", wrong_election))
+CASES.append(("mainline-64k-mean.steady", half_batch))
+
+
+@pytest.mark.parametrize("name,fault", CASES,
+                         ids=[f"{n}-{f.__name__}" for n, f in CASES])
+def test_fault_is_not_correct(name, fault, monkeypatch):
+    from repro.engine import jax_backend
+
+    monkeypatch.setattr(jax_backend, "due_dedup", jax_backend.due_dedup)
+    res = run(tiny(name, **getattr(fault, "engine", {})), faults=fault)
+    assert not res["correct"], res["checks"]
+    jax.clear_caches()
