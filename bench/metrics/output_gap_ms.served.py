@@ -1,0 +1,4 @@
+"""output_gap_ms.served: device-idle ms per pump inside outputs() (the
+knowledge dispatch and the readback), in the cells with client
+traffic. The reduction is in bench/harness/phases.py."""
+from harness.phases import output_gap_ms as read  # noqa: F401

@@ -112,6 +112,7 @@ from repro.kernels.wheel import (WHEEL_KERNELS, descent_reference,
                                  descent_tail, due_dedup, stage_rows,
                                  threshold_step)
 from repro.kernels.wheel._common import in_segment
+from repro.runtime import tracing
 
 NDIR = 3
 _I32 = jnp.int32
@@ -875,7 +876,13 @@ class JaxEngine:
         """One simulation cycle: drain each local lane's due bucket,
         route, accept, react; stage every re-entering/new row with its
         lane-relative delay ordinal; one boundary exchange routes the
-        staged rows to their owner lanes for the ranked appends."""
+        staged rows to their owner lanes for the ranked appends. Each
+        section runs in its named scope (`tracing.PHASES`)."""
+        with tracing.phases() as phase:
+            return self._cycle_body(st, phase)
+
+    def _cycle_body(self, st: DeviceState, phase) -> DeviceState:
+        phase("cycle.scan")
         pd, d = self.pad, self.d  # GLOBAL pad: sentinel/index space (the
         # plane's x rows may be a shard-local block of it)
         L = self.lanes
@@ -957,6 +964,7 @@ class JaxEngine:
             live = live & ~lost_m & ~delay_m
             n_lost_l = lost_m.reshape(Ln, WWl).sum(1).astype(_I32)
 
+        phase("cycle.descent")
         # ---- Alg. 1 delivery, two-phase (shared rules with
         # deliver_network_step, restructured for the width/latency split:
         # two full-width descent steps settle all but a few percent of
@@ -1028,6 +1036,7 @@ class JaxEngine:
         o_he = jnp.where(merged, stage[:, 3] != 0, o_he)
         fwd = live & ~acc & ~drop & ~spill
 
+        phase("cycle.accept")
         # ---- ACCEPT. One data winner per (peer, dir) link per cycle;
         # colliding rows defer (re-enter the wheel) and the monotone
         # per-link seq floor orders them on redelivery. An accepted ALERT
@@ -1096,6 +1105,7 @@ class JaxEngine:
         inbox = pl.put_link(st.inbox, data_idx, data_val)
         st = st._replace(inbox=inbox)
 
+        phase("cycle.react")
         # ---- react: gather-based test() + Send on the touched peers
         # (one representative window row per peer; work ∝ window, not
         # pad). The react VALUES are computed at compacted positions for
@@ -1148,6 +1158,7 @@ class JaxEngine:
         b_pay = back(pay)               # (WW, 3P) payload columns
         b_seq = back(seq2)              # (WW,)
 
+        phase("cycle.wheel")
         # ---- wheel maintenance (lane-local): slip one cycle, shift
         # leftovers to the front (revisited a revolution later).
         # Everything below only *writes* the wheel (sources are `sbuf`),
@@ -1188,6 +1199,7 @@ class JaxEngine:
         )(wheel, slip_rows, wcnt_s1)
         wcnt = jnp.where(col == s1, (wcnt_s1 + slip_k)[:, None], wcnt)
 
+        phase("cycle.stage")
         # ---- staging: one rigid per-lane block of every row that
         # (re-)enters a wheel — [WWl re-entry rows at window positions |
         # 3*WWl send rows at window-row-major positions]. The delay
@@ -1239,6 +1251,7 @@ class JaxEngine:
                 | blk_alert.astype(_U32) * META_ALERT)
         pkt = jnp.concatenate([staged, meta[:, :, None]], axis=2)
 
+        phase("cycle.probe")
         # ---- failure-detector probe emission (armed only): every local
         # peer row scans its links against the freshly-stamped `heard`;
         # links silent past `suspect_after` (and not re-probed within a
@@ -1282,6 +1295,7 @@ class JaxEngine:
             ).reshape(Ln, self.lane_rows * NDIR, roww + 1)
             pkt = jnp.concatenate([pkt, ppkt], axis=1)
 
+        phase("cycle.append")
         # ---- boundary exchange + ranked owner-lane appends: the ONE
         # lane-crossing step of the cycle. The exchange output is the
         # global lane-major staging order on every participant, so the
@@ -1311,6 +1325,7 @@ class JaxEngine:
             (st.awheel, acnt),
         )
 
+        phase("cycle.account")
         # accounting (per lane; hosts read sums): every first-entry live
         # window row is one consumed network delivery; continuations
         # (mid-descent spills and collision-loser redeliveries) were
@@ -1728,9 +1743,11 @@ class JaxEngine:
                 "dropped": dro, "lost_to_fault": lost}
 
     def outputs(self) -> np.ndarray:
-        out = knowledge_outputs(self.problem, self._st.inbox, self._st.x,
-                                self.pad)
-        return np.asarray(out)[: self.n].astype(np.int64)
+        with tracing.span("engine.knowledge"):
+            out = knowledge_outputs(self.problem, self._st.inbox, self._st.x,
+                                    self.pad)
+        with tracing.span("engine.readback"):
+            return np.asarray(out)[: self.n].astype(np.int64)
 
     def votes(self) -> np.ndarray:
         """(n,) scalar data (majority votes); (n, D) when D > 1."""
@@ -1745,12 +1762,15 @@ class JaxEngine:
         """Data-change upcall; `new_votes` is (k,) scalar data or (k, D)
         vectors in RAW units — quantized through the problem, exactly
         like `join`."""
-        idx = np.asarray(idx)
-        nd = self.problem.init_state(np.asarray(new_votes)).astype(np.int32)
-        st = self._st
-        x = st.x.at[jnp.asarray(idx)].set(jnp.asarray(nd))
-        touched = jnp.zeros(self.pad, bool).at[jnp.asarray(idx)].set(True)
-        self._st = self._react(st._replace(x=x), touched)
+        with tracing.span("engine.scatter"):
+            idx = np.asarray(idx)
+            nd = self.problem.init_state(
+                np.asarray(new_votes)).astype(np.int32)
+            st = self._st
+            x = st.x.at[jnp.asarray(idx)].set(jnp.asarray(nd))
+            touched = jnp.zeros(self.pad, bool).at[jnp.asarray(idx)].set(True)
+        with tracing.span("engine.react"):
+            self._st = self._react(st._replace(x=x), touched)
 
     def apply_coalesced(self, idx: np.ndarray, new_data: np.ndarray) -> int:
         """Serve-layer flush (see `repro.engine.base`): one coalesced
@@ -1984,8 +2004,21 @@ class JaxEngine:
         the dispatch boundary (eviction granularity = step granularity;
         the reference evicts per cycle — drive `step(1)` for exact
         timing)."""
-        self._st = self._steps(self._st, jnp.asarray(cycles, _I32))
-        self._fault_sweep()
+        with tracing.span("engine.dispatch"):
+            self._st = self._steps(self._st, jnp.asarray(cycles, _I32))
+            self._fault_sweep()
+
+    def op_phases(self) -> dict:
+        """{HLO instruction name: cycle phase} of the superstep program
+        as compiled for the current state, read from the compiled text's
+        metadata (a profiler trace drops it). The program is the one
+        `step` runs: it comes from JAX's in-memory cache, or else from
+        the persistent compile cache; none is built where `step` has run
+        at these shapes. An executable loaded from a persistent cache
+        keyed without metadata may carry an older program's, and then
+        maps fewer instructions."""
+        steps = self._steps.lower(self._st, jnp.asarray(1, _I32))
+        return tracing.op_phases(steps.compile().as_text())
 
     def block_until_ready(self) -> None:
         jax.block_until_ready(self._st)

@@ -124,6 +124,7 @@ def descent_tail_kernel(origin, dest, edge, has_edge, live, entry, pos_i,
         out_specs=[spec] * 5,
         out_shape=[shp_i, shp_i, shp_u, shp_u, shp_i],
         interpret=interpret,
+        name="wheel_descent",
         compiler_params=compiler_params(interpret),
     )(row_u(origin), row_u(dest), row_u(edge), row_b(has_edge),
       row_b(live), row_b(entry), row_u(pos_i), row_u(a_prev),

@@ -157,6 +157,7 @@ def due_dedup_kernel(flat, acc_d, acc_a, w_seq, link_seq,
                    jax.ShapeDtypeStruct((wwp, NDIR), _I32),
                    shp1, shp1, shp1, shp1, shp1],
         interpret=interpret,
+        name="wheel_dedup",
         compiler_params=compiler_params,
     )(col(f), row(f), col(ad), row(ad), col(aa), row(aa),
       col(pad_to(w_seq.astype(_I32), wwp)),

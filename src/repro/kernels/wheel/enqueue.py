@@ -85,6 +85,7 @@ def stage_rows_kernel(rows: jnp.ndarray, alert: jnp.ndarray,
         out_specs=pl.BlockSpec((_BM, roww), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, roww), _U32),
         interpret=interpret,
+        name="wheel_enqueue",
         compiler_params=compiler_params(interpret),
     )(rows, alert.astype(_I32).reshape(mp, 1),
       ordinal.astype(_I32).reshape(mp, 1),

@@ -70,6 +70,7 @@ def threshold_step_kernel(problem, in_cols: jnp.ndarray,
         out_specs=[spec(NDIR), spec(1), spec(NDIR * pw)],
         out_shape=[shp(NDIR), shp(1), shp(NDIR * pw)],
         interpret=interpret,
+        name="wheel_threshold",
         compiler_params=compiler_params(interpret),
     )(planes(in_cols), planes(out_cols), pad_to(x.astype(_I32).T, npad, axis=1))
     return viol[:, :n].T.astype(bool), out[0, :n], pay[:, :n].T

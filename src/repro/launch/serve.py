@@ -35,12 +35,18 @@ Latency accounting (consumed by
 `runtime.elastic.decision_latency_profile(trace=...)`): the server
 opens a *disturbance epoch* at the first flush (or churn upcall) that
 leaves the engine outputs off the current ground-truth decision, and
-closes it — emitting a `settle` trace record with the latency in
-cycles and wall ms — at the first window boundary where every peer
-again outputs the truth of the *current* data plane. Overlapping
+closes it — appending a `settle` record to `ThresholdServer.trace`
+with the latency in cycles and wall ms — at the first window boundary
+where every peer again outputs the truth of the *current* data plane.
+The wall clock is read after the blocking readback and the publish, so
+the latency covers what a subscriber waits for. Overlapping
 disturbances merge into the open epoch (latency is measured from the
 oldest unserved disturbance — the honest tail). Resolution is one
-serve window.
+serve window. `settle` records are the trace's only entries: flushes
+and transitions are counted in `stats()`, not recorded one by one.
+
+Each pump is a tree of host spans in a profiler trace (names and what
+they cover: `repro.runtime.tracing`).
 
 The deterministic workload generator (`gen_workload` /
 `replay_workload`) drives the same API from seeded per-window Poisson
@@ -59,6 +65,8 @@ import time
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
+
+from repro.runtime import tracing
 
 
 class Transition(NamedTuple):
@@ -152,23 +160,26 @@ class DecisionNotifier:
         if a.size > 1 and not (a[1:] > a[:-1]).all():
             order = np.argsort(a, kind="stable")
             a, o = a[order], o[order]
-        la, lo = self._last_addrs, self._last_outs
-        if la.shape == a.shape and (la == a).all():  # no churn since
-            changed = lo != o
-        elif la.size:
-            j = np.minimum(np.searchsorted(la, a), la.size - 1)
-            changed = (la[j] != a) | (lo[j] != o)
-        else:
-            changed = np.ones(a.size, bool)
-        self._last_addrs, self._last_outs = a, o
-        out = [Transition(int(t), frozenset(a[changed & (o == v)].tolist()),
-                          int(v))
-               for v in np.unique(o[changed])]
-        for tr in out:
-            self.published += 1
-            for cb in list(self._subs.values()):
-                cb(tr)
-                self.delivered += 1
+        with tracing.span("serve.diff"):
+            la, lo = self._last_addrs, self._last_outs
+            if la.shape == a.shape and (la == a).all():  # no churn since
+                changed = lo != o
+            elif la.size:
+                j = np.minimum(np.searchsorted(la, a), la.size - 1)
+                changed = (la[j] != a) | (lo[j] != o)
+            else:
+                changed = np.ones(a.size, bool)
+            self._last_addrs, self._last_outs = a, o
+            out = [Transition(int(t),
+                              frozenset(a[changed & (o == v)].tolist()),
+                              int(v))
+                   for v in np.unique(o[changed])]
+        with tracing.span("serve.deliver"):
+            for tr in out:
+                self.published += 1
+                for cb in list(self._subs.values()):
+                    cb(tr)
+                    self.delivered += 1
         return out
 
 
@@ -194,7 +205,7 @@ class ThresholdServer:
         self.clock = clock
         self.ring_buf = IngestionRing()
         self.notifier = DecisionNotifier()
-        self.trace: List[Dict] = []
+        self.trace: List[Dict] = []   # settle records (module doc)
         self.flushes = 0          # pump() calls
         self.applied = 0          # peer rows applied across all flushes
         self.stale_dropped = 0    # updates whose address had departed
@@ -257,42 +268,53 @@ class ThresholdServer:
         """One serve superstep: flush the ingestion ring at the cycle
         boundary, advance `cycles` (default: the server window), publish
         decision changes, account latency. Returns the transitions."""
-        wall0 = self.clock()
-        t0 = int(self.engine.t)
+        with tracing.span("serve.pump"):
+            wall0 = self.clock()
+            t0 = int(self.engine.t)
+            with tracing.span("serve.ingest"):
+                flush = self._ingest()
+            applied = self.engine.apply_coalesced(*flush) if flush else 0
+            self.flushes += 1
+            self.applied += applied
+
+            self.engine.step(int(cycles if cycles is not None
+                                 else self.window))
+            self.windows += 1
+
+            t1 = int(self.engine.t)
+            outputs = np.asarray(self.engine.outputs(), np.int64)
+            transitions = self.notifier.publish(
+                t1, np.asarray(self.engine.ring.addrs), outputs)
+            with tracing.span("serve.account"):
+                self._account(outputs, t0, wall0, t1)
+        return transitions
+
+    def _ingest(self) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+        """Drain the ring into the host mirror of the data plane; the
+        (live indices, values) to flush, or None."""
         batch = self.ring_buf.drain()
-        applied = 0
-        if batch:
-            addrs = np.asarray([a for a, _ in batch], np.int64)
-            idx = self._resolve(addrs)
-            live = idx >= 0
-            self.stale_dropped += int((~live).sum())
-            if live.any():
-                vals = _stack_values([v for (_, v), ok in zip(batch, live)
-                                      if ok])
-                li = idx[live]
-                applied = self.engine.apply_coalesced(li, vals)
-                new = self.engine.problem.init_state(vals)
-                self._ksum = self._ksum + (new - self._data[li]).sum(0)
-                self._data[li] = new
-                self._truth = self._compute_truth()
-                self._dirty = True
-        self.flushes += 1
-        self.applied += applied
-        self.trace.append({"kind": "flush", "t": t0, "applied": applied,
-                           "submitted": len(batch), "wall": wall0})
+        if not batch:
+            return None
+        addrs = np.asarray([a for a, _ in batch], np.int64)
+        idx = self._resolve(addrs)
+        live = idx >= 0
+        self.stale_dropped += int((~live).sum())
+        if not live.any():
+            return None
+        vals = _stack_values([v for (_, v), ok in zip(batch, live) if ok])
+        li = idx[live]
+        new = self.engine.problem.init_state(vals)
+        self._ksum = self._ksum + (new - self._data[li]).sum(0)
+        self._data[li] = new
+        self._truth = self._compute_truth()
+        self._dirty = True
+        return li, vals
 
-        self.engine.step(int(cycles if cycles is not None else self.window))
-        self.windows += 1
-
-        t1 = int(self.engine.t)
-        wall1 = self.clock()
-        outputs = np.asarray(self.engine.outputs(), np.int64)
-        transitions = self.notifier.publish(
-            t1, np.asarray(self.engine.ring.addrs), outputs)
-        for tr in transitions:
-            self.trace.append({"kind": "transition", "t": tr.t,
-                               "peers": len(tr.peers), "output": tr.output,
-                               "wall": wall1})
+    def _account(self, outputs: np.ndarray, t0: int, wall0: float,
+                 t1: int) -> None:
+        """Convergence against the truth and the disturbance epoch: a
+        `settle` record when an open epoch closes, its wall time read
+        here, after the readback and the publish."""
         conv = bool(self.engine.problem.converged(
             np, outputs, self._truth).all())
         if self._dirty and not conv and self._epoch_t0 is None:
@@ -305,12 +327,11 @@ class ThresholdServer:
                 self.trace.append({
                     "kind": "settle", "t": t1,
                     "cycles": t1 - self._epoch_t0,
-                    "wall_ms": (wall1 - self._epoch_wall) * 1e3,
+                    "wall_ms": (self.clock() - self._epoch_wall) * 1e3,
                 })
                 self._epoch_t0 = self._epoch_wall = None
             self._dirty = False
         self.converged = conv
-        return transitions
 
     def run(self, windows: int) -> None:
         for _ in range(windows):
