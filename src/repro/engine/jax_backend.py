@@ -92,7 +92,7 @@ equivalence and the seeded-RNG tolerance are specified in DESIGN.md
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -112,6 +112,7 @@ from repro.kernels.wheel import (WHEEL_KERNELS, descent_reference,
                                  descent_tail, due_dedup, stage_rows,
                                  threshold_step)
 from repro.kernels.wheel._common import in_segment
+from repro.kernels.wheel.due_dedup import dedup_grid
 from repro.runtime import tracing
 
 NDIR = 3
@@ -430,6 +431,7 @@ class JaxEngine:
     """Device-backed `MajorityEngine` (see `repro.engine.base`)."""
 
     backend = "jax"
+    n_shards = 1  # devices the peer plane and the lanes split over
 
     def __init__(self, ring: Ring, votes: np.ndarray, seed: int = 0,
                  capacity_per_peer: int = 6, work_budget: int = 0,
@@ -1063,12 +1065,13 @@ class JaxEngine:
                 flat, jnp.broadcast_to(st.t.astype(_I32), (WW,)), acc))
         if "dedup" in self._wk:
             # window-local fused election: all decisions (including the
-            # react representative and the alert force mask) come from an
-            # O(WW^2) blocked all-pairs kernel over the window rows —
-            # no O(pad) plane, no collectives
+            # react representative and the alert force mask) come from a
+            # blocked all-pairs kernel over each lane's window rows
+            # (O(WW^2/Ln), the ownership rule above) — no O(pad) plane,
+            # no collectives
             link_seq = pl.take_link(st.inbox, flat)[:, self.pw]
             (winner, loser, fresh, alert_write, is_rep, aforce) = due_dedup(
-                flat, acc_d, acc_a, w_seq, link_seq, nl=sent,
+                flat, acc_d, acc_a, w_seq, link_seq, nl=sent, lanes=Ln,
                 use_kernel=True, interpret=self._wk_interp,
             )
             abest = None
@@ -1712,6 +1715,17 @@ class JaxEngine:
         subset on a TPU, none elsewhere (enabled kernels then run in
         interpret mode, the parity surface)."""
         return () if self._wk_interp else tuple(sorted(self._wk))
+
+    @property
+    def dedup_grid_share(self) -> Optional[float]:
+        """Share of the all-pairs block grid over the (shard-local)
+        drain window that the lane-blocked dedup election visits; static,
+        from the window's shape. None where the XLA plane elects."""
+        if "dedup" not in self._wk:
+            return None
+        visited, whole = dedup_grid(self.window_l,
+                                    self.lanes // self.n_shards)
+        return visited / whole
 
     @property
     def deferral_rate(self) -> float:

@@ -7,7 +7,7 @@ tests/test_kernels.py in interpret mode on CPU CI):
 
   * `due_dedup`       — fused due-scan + accept-dedup: window-local
     winner / representative / alert-force election replacing the dense
-    per-link scatter-max plane;
+    per-link scatter-max plane, within each owner lane;
   * `stage_rows`      — ordinal-ranked enqueue staging: the lane-local
     delay-class gather + DELIVER_T stamping of the cycle's rigid
     staging block in one blocked pass (mesh-invariant ordinals);

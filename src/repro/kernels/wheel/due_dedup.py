@@ -8,13 +8,17 @@ cells, then gathers back) — O(pad) memory traffic for an O(window)
 question.
 
 This kernel answers the question window-locally instead: for window
-rows i, j (both <= WW), row j beats row i on the same link iff
-``flat[j] == flat[i]`` — a blocked O(WW^2) all-pairs max that is pure
-VPU compute (no scatter, no O(pad) plane). The window it sees is the
-SHARD-LOCAL drain window: under the owner-partitioned wheel every row
-already lives in the lane of its DEST owner, so winner election is
-lane-local by invariant and the kernel runs per shard with no
-collective either way. One fused pass accumulates, per window row,
+rows i, j, row j beats row i on the same link iff
+``flat[j] == flat[i]`` — a blocked all-pairs max that is pure VPU
+compute (no scatter, no O(pad) plane). The window it sees is the
+SHARD-LOCAL drain window, laid out lane-major: owner lane l's
+``WW/Ln`` rows sit at ``[l*WW/Ln, (l+1)*WW/Ln)``. Under the
+owner-partitioned wheel every row lives in the lane of its DEST owner,
+so every accepting row's link (and peer) belongs to its own lane: rows
+of different lanes never share a link or a peer. The election is
+therefore lane-local by invariant — per shard with no collective, and
+within a shard per lane: the kernel compares rows of one lane only,
+O(WW^2/Ln) pairs. One fused pass accumulates, per window row,
 
   * ``best``  — max window index of an accepting DATA row on its link,
   * ``abest`` — same for ALERT rows,
@@ -26,12 +30,18 @@ collective either way. One fused pass accumulates, per window row,
 and finalizes the elementwise decisions (winner / loser / fresh /
 alert_write / is_rep) on the last j-block. Winner election is a
 deterministic max, so the window-local and plane formulations are
-bit-identical — `due_dedup_reference` below IS the plane formulation
-(mirroring the engine's fallback path), and the parity tests drive the
-kernel against it.
+bit-identical wherever the ownership rule holds (the engine reads
+``aforce`` only at accepting rows) — `due_dedup_reference` below IS
+the plane formulation (mirroring the engine's fallback path), and the
+parity tests drive the kernel against it.
 
-Grid: (i-blocks, j-blocks), j innermost and sequential (accumulation in
-the output refs, init at j == 0); the i dimension is parallel.
+Grid: (lane, i-block, j-block) over each lane's rows, padded per lane
+to a multiple of the block edge (padding rows carry ``flat = -1`` and no
+accept flag); j innermost and sequential (accumulation in the output
+refs, init at j == 0), lane and i parallel. Window indices inside the
+kernel are lane-local; the election only compares them within a lane.
+One lane (the default, and what an odd pad gives) is the whole-window
+grid.
 """
 from __future__ import annotations
 
@@ -44,6 +54,9 @@ from repro.kernels.wheel._common import on_tpu, pad_to
 
 _I32 = jnp.int32
 NDIR = 3
+# block edge: on a v5e chip a grid step costs about as much as 80k row
+# pairs, so 512 beat 256 and 128 at both benchmark widths
+BLOCK = 512
 
 
 def due_dedup_reference(flat, acc_d, acc_a, w_seq, link_seq, nl: int):
@@ -75,25 +88,47 @@ def due_dedup_reference(flat, acc_d, acc_a, w_seq, link_seq, nl: int):
     return winner, loser, fresh, alert_write, is_rep, aforce
 
 
-def due_dedup_kernel(flat, acc_d, acc_a, w_seq, link_seq,
-                     block: int = 512, interpret: bool = True):
+def _lane_block(ww_l: int, block: int) -> int:
+    """The block edge actually used for a per-lane width `ww_l`: a lane
+    narrower than the block is one block of its own width."""
+    return min(block, max(ww_l, 8))
+
+
+def dedup_grid(ww_l: int, lanes: int, block: int = BLOCK):
+    """(block pairs the lane-blocked election visits, block pairs of the
+    all-pairs grid over the whole window) for `lanes` lanes of `ww_l`
+    rows each. Static: it follows from the window's shape alone."""
+    block = _lane_block(ww_l, block)
+    per_lane = -(-ww_l // block)
+    whole = -(-(lanes * ww_l) // block)
+    return lanes * per_lane * per_lane, whole * whole
+
+
+def due_dedup_kernel(flat, acc_d, acc_a, w_seq, link_seq, lanes: int = 1,
+                     block: int = BLOCK, interpret: bool = True):
     ww = flat.shape[0]
-    block = min(block, max(ww, 8))
-    wwp = ww + (-ww % block)
-    nb = wwp // block
-    f = pad_to(flat.astype(_I32), wwp, fill=-1)
-    ad = pad_to(acc_d.astype(_I32), wwp)
-    aa = pad_to(acc_a.astype(_I32), wwp)
-    col = lambda a: a[:, None]
-    row = lambda a: a[None, :]
+    wwl = ww // lanes  # lane-major: lane l's rows at [l*wwl, (l+1)*wwl)
+    block = _lane_block(wwl, block)
+    wwlp = wwl + (-wwl % block)
+    nb = wwlp // block
+
+    def lanes_of(a, fill=0):  # (WW,) -> (lanes, WWl padded) per lane
+        return pad_to(a.astype(_I32).reshape(lanes, wwl), wwlp, axis=1,
+                      fill=fill)
+
+    f = lanes_of(flat, fill=-1)
+    ad = lanes_of(acc_d)
+    aa = lanes_of(acc_a)
+    col = lambda a: a[:, :, None]
+    row = lambda a: a[:, None, :]
 
     def kern(fc_ref, fr_ref, adc_ref, adr_ref, aac_ref, aar_ref,
              wsc_ref, lsc_ref,
              best_ref, abest_ref, rep_ref, aforce_ref,
              win_ref, lose_ref, fresh_ref, aw_ref, isrep_ref):
-        i = pl.program_id(0)
-        j = pl.program_id(1)
-        nj = pl.num_programs(1)
+        i = pl.program_id(1)
+        j = pl.program_id(2)
+        nj = pl.num_programs(2)
         fi = fc_ref[...]                                  # (BI, 1)
         fj = fr_ref[...]                                  # (1, BJ)
         dj = adr_ref[...] != 0
@@ -139,41 +174,45 @@ def due_dedup_kernel(flat, acc_d, acc_a, w_seq, link_seq,
             aw_ref[...] = (ai & (b < 0)).astype(_I32)
             isrep_ref[...] = ((di | ai) & (wi_i == rep_ref[...])).astype(_I32)
 
-    cspec = pl.BlockSpec((block, 1), lambda i, j: (i, 0))
-    rspec = pl.BlockSpec((1, block), lambda i, j: (0, j))
-    shp1 = jax.ShapeDtypeStruct((wwp, 1), _I32)
+    # the lane axis is squeezed out of every block: a kernel instance
+    # sees (block, 1) columns of lane l's i-block and (1, block) rows of
+    # its j-block, so rows of different lanes are never compared
+    cspec = pl.BlockSpec((None, block, 1), lambda l, i, j: (l, i, 0))
+    rspec = pl.BlockSpec((None, 1, block), lambda l, i, j: (l, 0, j))
+    shp1 = jax.ShapeDtypeStruct((lanes, wwlp, 1), _I32)
     compiler_params = None
     if not interpret:
         compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
     (best, abest, rep, aforce, win, lose, fresh, aw, isrep) = pl.pallas_call(
         kern,
-        grid=(nb, nb),
+        grid=(lanes, nb, nb),
         in_specs=[cspec, rspec, cspec, rspec, cspec, rspec, cspec, cspec],
         out_specs=[cspec, cspec, cspec,
-                   pl.BlockSpec((block, NDIR), lambda i, j: (i, 0)),
+                   pl.BlockSpec((None, block, NDIR),
+                                lambda l, i, j: (l, i, 0)),
                    cspec, cspec, cspec, cspec, cspec],
         out_shape=[shp1, shp1, shp1,
-                   jax.ShapeDtypeStruct((wwp, NDIR), _I32),
+                   jax.ShapeDtypeStruct((lanes, wwlp, NDIR), _I32),
                    shp1, shp1, shp1, shp1, shp1],
         interpret=interpret,
         name="wheel_dedup",
         compiler_params=compiler_params,
     )(col(f), row(f), col(ad), row(ad), col(aa), row(aa),
-      col(pad_to(w_seq.astype(_I32), wwp)),
-      col(pad_to(link_seq.astype(_I32), wwp)))
-    sl = lambda a: a[:ww, 0].astype(bool)
-    return (sl(win), sl(lose), sl(fresh), sl(aw), sl(isrep),
-            aforce[:ww].astype(bool))
+      col(lanes_of(w_seq)), col(lanes_of(link_seq)))
+    sl = lambda a: a[:, :wwl].reshape(ww, -1).astype(bool)
+    return (sl(win)[:, 0], sl(lose)[:, 0], sl(fresh)[:, 0], sl(aw)[:, 0],
+            sl(isrep)[:, 0], sl(aforce))
 
 
-def due_dedup(flat, acc_d, acc_a, w_seq, link_seq, nl: int,
-              use_kernel: bool = True, block: int = 512, interpret=None):
+def due_dedup(flat, acc_d, acc_a, w_seq, link_seq, nl: int, lanes: int = 1,
+              use_kernel: bool = True, block: int = BLOCK, interpret=None):
     """Dispatch: window-local Pallas election, or the dense-plane XLA
     reference (bit-identical — deterministic max election)."""
     if use_kernel and flat.shape[0] >= 8:
         if interpret is None:
             interpret = not on_tpu()
         return due_dedup_kernel(flat, acc_d, acc_a, w_seq, link_seq,
-                                block=block, interpret=interpret)
+                                lanes=lanes, block=block,
+                                interpret=interpret)
     return due_dedup_reference(flat, acc_d, acc_a, w_seq, link_seq, nl)

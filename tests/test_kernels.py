@@ -31,7 +31,7 @@ from repro.engine.problems import get_problem
 from repro.kernels.wheel import WHEEL_KERNELS
 from repro.kernels.wheel._common import in_segment
 from repro.kernels.wheel.descent import descent_reference, descent_tail_kernel
-from repro.kernels.wheel.due_dedup import (due_dedup_kernel,
+from repro.kernels.wheel.due_dedup import (dedup_grid, due_dedup_kernel,
                                            due_dedup_reference)
 from repro.kernels.wheel.enqueue import (stage_rows_kernel,
                                          stage_rows_reference)
@@ -144,6 +144,69 @@ def test_due_dedup_no_alerts():
     for w, g in zip(want, got):
         _eq(g, w)
     assert not np.asarray(got[3]).any()  # no alert_write without alerts
+
+
+def _lane_dedup_inputs(lanes, ww_l, seed, alert_frac=0.2):
+    """A lane-major window under the ownership rule: every row's link
+    lies in its own lane's link range (lane l's peers own links
+    [l * 3P, (l + 1) * 3P)), with few peers a lane, so links collide."""
+    rng = np.random.default_rng(seed)
+    links = NDIR * max(ww_l // 9, 1)  # links per lane
+    lane = np.repeat(np.arange(lanes), ww_l)
+    flat = jnp.asarray(lane * links + rng.integers(0, links, lanes * ww_l),
+                       jnp.int32)
+    _, acc_d, acc_a, w_seq, link_seq = _dedup_inputs(lanes * ww_l, links,
+                                                     seed, alert_frac)
+    return (flat, acc_d, acc_a, w_seq, link_seq), lanes * links
+
+
+@pytest.mark.parametrize("lanes,ww_l,block", [
+    (1, 80, 64), (2, 80, 64), (8, 80, 512), (2, 257, 128), (8, 257, 512)])
+@pytest.mark.parametrize("alert_frac", [0.2, 0.0])
+def test_due_dedup_lanes_match_plane(lanes, ww_l, block, alert_frac):
+    """The lane-blocked grid compares rows of one lane only; under the
+    ownership rule that is the whole-window election, row for row. Lane
+    widths are no multiple of the block (80 is the engine's `window_l`
+    at pad 64; 257 stands for the 2^19 cell's 4,112)."""
+    args, nl = _lane_dedup_inputs(lanes, ww_l, lanes * 31 + ww_l,
+                                  alert_frac)
+    want = due_dedup_reference(*args, nl=nl)
+    got = due_dedup_kernel(*args, lanes=lanes, block=block, interpret=True)
+    names = ("winner", "loser", "fresh", "alert_write", "is_rep", "aforce")
+    for w, g, name in zip(want, got, names):
+        _eq(g, w, f"lanes={lanes} ww_l={ww_l} block={block} {name}")
+    if not alert_frac:
+        assert not np.asarray(got[3]).any()
+
+
+@pytest.mark.parametrize("ww_l,block,visited,whole", [
+    (4112, 512, 648, 4225),    # 2^19 cell: 8 x 9^2 of 65^2 block pairs
+    (2064, 512, 200, 1089),    # 2^16 cell (pad 2^17): 8 x 5^2 of 33^2
+    (4112, 256, 2312, 16641),  # 8 x 17^2 of 129^2
+    (2064, 256, 648, 4225),    # 8 x 9^2 of 65^2
+])
+def test_dedup_grid_pins_the_cells(ww_l, block, visited, whole):
+    assert dedup_grid(ww_l, 8, block) == (visited, whole)
+    # one lane is the whole-window grid
+    assert dedup_grid(8 * ww_l, 1, block) == (whole, whole)
+
+
+def test_dedup_grid_narrow_lane():
+    """A lane narrower than the block is one block of its own width: the
+    engine's `window_l` at pad 64 (80 rows a lane, 8 lanes)."""
+    assert dedup_grid(80, 8) == (8, 64)
+    assert dedup_grid(4112, 8) == dedup_grid(4112, 8, 512)
+
+
+def test_engine_dedup_grid_share():
+    """The engine reports the share of the all-pairs grid its election
+    visits, from the window's shape; none where the XLA plane elects."""
+    ring = Ring.random(48, d=16, seed=5)
+    votes = np.zeros(48, np.int64)
+    ker = JaxEngine(ring, votes, kernel="pallas")
+    visited, whole = dedup_grid(ker.window_l, ker.lanes)
+    assert ker.lanes == 8 and ker.dedup_grid_share == visited / whole
+    assert JaxEngine(ring, votes, kernel="ref").dedup_grid_share is None
 
 
 # -- stage_rows: ordinal-keyed delay classes + DELIVER_T stamping ---------

@@ -99,6 +99,27 @@ def test_due_dedup_compiles(one_chip, widths):
              i, b, b, i, i)
 
 
+@pytest.mark.parametrize("pad,budget", [(PAD, 0), (1 << 19, 1 << 15),
+                                        (1 << 17, 0)])
+def test_due_dedup_lanes_compile(one_chip, pad, budget):
+    """The lane-blocked election at the engine's own lanes and per-lane
+    window (pad 2^20, and the two benchmark deployments' 2^19 and
+    2^17)."""
+    from repro.core.dht import Ring
+    from repro.engine.jax_backend import JaxEngine
+    from repro.kernels.wheel.due_dedup import due_dedup_kernel
+
+    eng = JaxEngine(Ring.random(64, 32, seed=0), np.zeros(64, np.int64),
+                    pad_to=pad, work_budget=budget, _defer_state=True)
+    ln, ww = eng.lanes, eng.lanes * eng.window_l
+    assert ln == 8
+    i = jax.ShapeDtypeStruct((ww,), jnp.int32, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((ww,), jnp.bool_, sharding=one_chip)
+    _compile(lambda f, d, a, w, k: due_dedup_kernel(f, d, a, w, k, lanes=ln,
+                                                    interpret=False),
+             i, b, b, i, i)
+
+
 def test_stage_rows_compiles(one_chip, widths):
     from repro.kernels.wheel.enqueue import stage_rows_kernel
 
