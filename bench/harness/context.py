@@ -25,7 +25,8 @@ class Context:
         return slice(self.win.first_window_pump, self.win.last_window_pump)
 
     def latencies(self) -> Tuple[np.ndarray, np.ndarray]:
-        """(latency_s, failed) of every update due in the window."""
+        """(latency_s, failed) of every update, join and leave due in
+        the window."""
         if self._lat is None:
             w, p = self.win, self.win.pumps
             self._lat = decision_latencies(
@@ -34,9 +35,10 @@ class Context:
         return self._lat
 
     def operations(self) -> Tuple[int, int]:
-        """(attempted, failed): updates due in the window, or for a mix
-        without updates the pumps of the window (each fails if it broke
-        conservation or dropped a wheel row)."""
+        """(attempted, failed): updates, joins and leaves due in the
+        window, or for a mix whose operation is the window the pumps of
+        the window (each fails if it broke conservation or dropped a
+        wheel row)."""
         if self.win.cell.traffic.get("operation") == "window":
             ok = self.win.pumps.ok[self.window_pumps()]
             return len(ok), int(sum(not v for v in ok))

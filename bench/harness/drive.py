@@ -1,10 +1,13 @@
 """The served window: set-up, warm-up, the measured window, the drain.
 
-The window drives `ThresholdServer.submit` and `pump()` over
-`make_engine("jax", ...)` exactly as users call them. Host spans come
-from the harness's own files: `jax.profiler.TraceAnnotation` wrappers
-put on the instance around the engine's `apply_coalesced`, `step` and
-`outputs` and the notifier's `publish`; no program file is touched.
+The window drives `ThresholdServer.submit`, `join`, `leave_addr` and
+`pump()` over `make_engine("jax", ...)` exactly as users call them:
+each entry of the schedule through its own entry point, in due order.
+Host spans come from the harness's own files: `jax.profiler.
+TraceAnnotation` wrappers put on the instance around the engine's
+`apply_coalesced`, `step` and `outputs` and the notifier's `publish`,
+and `bench.membership` around each join and leave; no program file is
+touched.
 Each pump is timed on the harness's clock after `pump()` returns, and
 `pump()` returns only after `outputs()` has read the device back.
 """
@@ -72,7 +75,10 @@ class HostSpans:
 
 class Pumps:
     """Every pump in order: start, end, settled, engine cycle after it,
-    the flush it applied, and how many transitions existed after it."""
+    the flush it applied (submitted addresses and values, in submit
+    order), the membership events called since the pump before it (in
+    call order: ("join", addr, value) or ("leave", addr, None)), and how
+    many transitions existed after it."""
 
     def __init__(self):
         self.start: List[float] = []
@@ -80,6 +86,7 @@ class Pumps:
         self.settled: List[bool] = []
         self.t: List[int] = []
         self.flush: List = []
+        self.events: List[List] = []
         self.transitions_upto: List[int] = []
         self.ok: List[bool] = []
 
@@ -91,28 +98,25 @@ def build(cell: Cell, seed: int):
     # a configuration may fix its deployment (ring, data, hot peers,
     # update values) so that every seed serves the same work, in
     # another order (bench/harness/traffic.py)
-    dep = np.random.default_rng(cfg.get("deployment_seed", seed))
-    ring_seed, engine_seed, data_seed, _ = (
-        int(s) for s in dep.integers(0, 2**31 - 1, 4))
-    traffic_seed = int(np.random.default_rng(seed).integers(
-        0, 2**31 - 1, 4)[3])
-    data_rng = np.random.default_rng(data_seed)
-    params = T.data_params(cfg["data"], data_rng)
-    ring = Ring.random(cfg["n"], cfg["d"], seed=ring_seed)
-    values0 = T.draw_data(cfg["data"], data_rng, cfg["n"], params)
+    st = T.streams(cfg, seed)
+    ring = Ring.random(cfg["n"], cfg["d"], seed=st["ring_seed"])
+    values0, params = st["values0"], st["params"]
     # `override` (bench/control.py) switches the program off what the
     # configuration states; the reference never sees it
     over = cfg.get("override", {})
     prob = dict(cfg["problem"], **over.get("problem", {}))
     problem = get_problem(prob.pop("name"), **prob)
-    engine = make_engine("jax", ring, values0, seed=engine_seed,
+    engine = make_engine("jax", ring, values0, seed=st["engine_seed"],
                          problem=problem,
                          **dict(cfg["engine"], **over.get("engine", {})))
     server = ThresholdServer(engine, window=cfg["window"])
+    addrs = np.asarray(ring.addrs, np.uint64)
+    churn = T.Churn(addrs, cfg["d"], seed, st["deployment_seed"]) \
+        if "membership" in cell.traffic else None
     return {"server": server, "engine": engine, "values0": values0,
-            "params": params, "addrs": np.asarray(ring.addrs, np.uint64),
-            "traffic_rng": np.random.default_rng(traffic_seed),
-            "deployment_rng": data_rng}
+            "params": params, "addrs": addrs, "churn": churn,
+            "traffic_rng": st["traffic_rng"],
+            "deployment_rng": st["deployment_rng"]}
 
 
 class Window:
@@ -126,6 +130,7 @@ class Window:
         self._annotation = jax.profiler.TraceAnnotation
         self.pumps = Pumps()
         self.transitions: List = []
+        self._events: List = []        # called since the last pump
         self.marks: Dict[str, Dict] = {}
 
     # -- phases ---------------------------------------------------------------
@@ -134,8 +139,8 @@ class Window:
         tr = self.cell.traffic
         b = build(self.cell, self.seed)
         self.__dict__.update(b)
-        self.sched = T.Schedule(np.zeros(0), np.zeros(0, np.int64),
-                                np.zeros(0))
+        self.sched = T.updates_only(np.zeros(0), np.zeros(0, np.int64),
+                                    np.zeros(0))
         self._next = self._flushed = 0
         self.server.subscribe(self.transitions.append)
         self.spans = HostSpans(self.server, self.clock)
@@ -149,6 +154,8 @@ class Window:
         for _ in range(int(tr.get("warmup_pumps", 0))):
             self._pump_and_log()
         self._warm_flush_sizes(int(tr.get("warm_batch_max", 0)))
+        if self.churn is not None:
+            self._warm_membership(float(tr.get("settle_cap_s", 0)))
         self.engine.block_until_ready()
         self.setup_phases["warm-up"] = self.clock()
         self.marks["setup"] = counter.mark()
@@ -159,7 +166,7 @@ class Window:
         tracing = self.tracer is not None
         self.sched = T.schedule(tr, self.cell.config["data"], self.params,
                                 self.seconds, n, self.traffic_rng,
-                                self.deployment_rng)
+                                self.deployment_rng, self.churn)
         N = self.sched.due.size
         self.submit_t = np.full(N, np.nan)
         self._next = self._flushed = 0
@@ -214,16 +221,47 @@ class Window:
     # -- pieces ---------------------------------------------------------------
 
     def _submit(self, hi: int) -> None:
-        """Submit the arrivals [next, hi) of the schedule, in order."""
+        """Send the arrivals [next, hi) of the schedule, in order, each
+        to its own entry point; record when each call returned."""
         s = self.sched
         if hi <= self._next:
             return
         with self._annotation("bench.submit"):
             for i in range(self._next, hi):
-                self.server.submit(int(self.addrs[s.peer[i]]),
-                                   s.values[i].item())
+                if s.kind[i]:
+                    self._member(int(s.kind[i]), int(s.addr[i]),
+                                 s.values[i].item())
+                else:
+                    self.server.submit(int(self.addrs[s.peer[i]]),
+                                       s.values[i].item())
                 self.submit_t[i] = self.clock()
         self._next = hi
+
+    def _member(self, kind: int, addr: int, value) -> None:
+        """One join or leave through the server, in a span of its own,
+        recorded for the next pump."""
+        with self._annotation("bench.membership"):
+            if kind == T.JOIN:
+                self.server.join(addr, value)
+            else:
+                self.server.leave_addr(addr)
+        self._events.append(("join", addr, value) if kind == T.JOIN
+                            else ("leave", addr, None))
+
+    def _warm_membership(self, cap: float) -> None:
+        """One join at a fresh address, then its leave, each followed by
+        a pump, through the server and recorded: every program a join or
+        a leave dispatches is built (or loaded) before the window. Then
+        pumps until the server is settled again, up to `cap` seconds."""
+        addr = int(self.churn.fresh(1)[0])
+        value = T.draw_data(self.cell.config["data"],
+                            self.churn.deployment, 1, self.params)[0]
+        self._member(T.JOIN, addr, value.item())
+        self._pump_and_log()
+        self._member(T.LEAVE, addr, None)
+        self._pump_and_log()
+        if not self.server.settled:
+            self._pump_until_settled(self.clock() + cap, None)
 
     def _warm_flush_sizes(self, k_max: int) -> None:
         """One pump for every flush size 1..`k_max`, through the server:
@@ -235,15 +273,17 @@ class Window:
             vals = self.values0[peers]
             for p, v in zip(peers, vals):
                 self.server.submit(int(self.addrs[p]), v.item())
-            self._pump(peers, vals)
+            self._pump(self.addrs[peers], vals)
 
     def _pump_and_log(self) -> None:
-        """One pump; its flush is everything submitted since the last."""
+        """One pump; its flush is every update submitted since the last."""
         lo, hi = self._flushed, self._next
-        self._pump(self.sched.peer[lo:hi], self.sched.values[lo:hi])
+        s = self.sched
+        up = s.kind[lo:hi] == T.UPDATE
+        self._pump(self.addrs[s.peer[lo:hi][up]], s.values[lo:hi][up])
         self._flushed = hi
 
-    def _pump(self, peers, values) -> None:
+    def _pump(self, addrs, values) -> None:
         p = self.pumps
         ps = self.clock()
         with self._annotation("bench.pump"):
@@ -253,7 +293,9 @@ class Window:
         p.end.append(pe)
         p.settled.append(bool(self.server.settled))
         p.t.append(int(self.engine.t))
-        p.flush.append((np.asarray(peers, np.int64), np.asarray(values)))
+        p.flush.append((np.asarray(addrs, np.uint64), np.asarray(values)))
+        p.events.append(self._events)
+        self._events = []
         p.transitions_upto.append(len(self.transitions))
         if self.cell.traffic.get("operation") == "window":
             p.ok.append(self._window_ok())
@@ -287,6 +329,7 @@ class Window:
         except AssertionError:
             conserved = False
         return {
+            "addrs": np.asarray(eng.ring.addrs, np.uint64),
             "data": eng.data(), "outputs": np.asarray(eng.outputs()),
             "conserved": conserved, "dropped": int(eng.dropped),
             "t": int(eng.t), "settled": bool(self.server.settled),

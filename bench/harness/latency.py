@@ -4,9 +4,11 @@ included.
 An update's decision latency runs from its due time to the return of
 the first pump that started after the update was submitted and ended
 with the server settled (every peer's output on the truth of the
-current data). An update that no pump settles before the drain cap is
-a failure; it enters the percentiles at its censored age, measured to
-the end of the drain, so a stall cannot hide by failing.
+current data). A join or a leave is an operation in the same way: from
+its due time to the first pump that started after the call returned
+and ended settled. An operation that no pump settles before the drain
+cap is a failure; it enters the percentiles at its censored age,
+measured to the end of the drain, so a stall cannot hide by failing.
 """
 from __future__ import annotations
 

@@ -6,6 +6,7 @@ import time
 from typing import Callable, Dict, Optional
 
 from . import check, device, spec
+from . import traffic as T
 from .context import Context
 from .drive import Window
 from .trace import Tracer
@@ -37,8 +38,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     final = win.final_state()
     rule = spec.reference_rule(cell.config["problem"]["name"])
     numbers = check.compare(
-        rule, cell.config["problem"], win.values0, win.addrs,
-        win.pumps.flush, win.pumps.settled, win.pumps.transitions_upto,
+        rule, cell.config["problem"], win.values0, win.addrs, win.pumps,
         win.transitions, final)
     correct = all(v <= lim for _, v, lim in numbers)
 
@@ -73,6 +73,10 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         f"{win.w1 - win.w0:.3f} s, {win.window_cycles} cycles in "
         f"{win.last_window_pump - win.first_window_pump} pumps, "
         f"attempted {attempted}, failed {failed}")
+    joins, leaves = (int((win.sched.kind == k).sum())
+                     for k in (T.JOIN, T.LEAVE))
+    log(f"membership: joins {joins}, leaves {leaves}, stale_dropped "
+        f"{win.server.stale_dropped}, final n {final['addrs'].size}")
     log(f"programs: set-up {s['compiles']} ({s['compile_s']:.3f} s), "
         f"{s['cache_hits']} of them loaded from the compile cache; window "
         f"{w['compiles'] - s['compiles']}, "

@@ -106,8 +106,7 @@ def main(argv=None) -> int:
     final = win.final_state()
     rule = spec.reference_rule(cell.config["problem"]["name"])
     numbers = check.compare(
-        rule, cell.config["problem"], win.values0, win.addrs,
-        win.pumps.flush, win.pumps.settled, win.pumps.transitions_upto,
+        rule, cell.config["problem"], win.values0, win.addrs, win.pumps,
         win.transitions, final)
     print(json.dumps({"checks": {n: v for n, v, _ in numbers}}),
           flush=True)
